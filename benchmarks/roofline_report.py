@@ -15,6 +15,7 @@ import glob
 import json
 import os
 
+from repro.launch.mesh import V5E_DEVICE_KIND
 from repro.obs import profile
 
 ARCH_ORDER = ["gemma3-4b", "internvl2-26b", "qwen3-moe-30b-a3b",
@@ -64,7 +65,7 @@ def load(dir_, multipod=False, tag=""):
 
 
 def roofline_table(recs):
-    peaks = profile.peak_table("tpu")
+    peaks = profile.peak_table(V5E_DEVICE_KIND)
     lines = ["| arch | shape | compute s | memory s | collective s | bound | "
              "MODEL_FLOPS | useful ratio | what moves the bound |",
              "|---|---|---|---|---|---|---|---|---|"]

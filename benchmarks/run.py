@@ -19,6 +19,7 @@ import json
 import os
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs.timing import monotonic
 
 
@@ -39,11 +40,7 @@ def run_tables(results):
         # fail ("Failed to materialize symbols") after many executables
         jax.clear_caches()
         print(f"# {title}")
-        try:
-            out = fn()
-        except Exception as e:  # isolate one table's failure
-            print(f"{key},ERROR,{type(e).__name__}: {e}")
-            return None
+        out = fn()   # a failed table fails the run
         rows, claims = out[0], out[1]
         _emit(rows)
         all_claims.update(claims)
@@ -58,8 +55,7 @@ def run_tables(results):
             T.table_4_cluster_count)
     out = section("Table 5/6 + Fig 2 — overfitting on selected subset, L2",
                   "table_5_6", T.table_5_6_overfitting_and_l2)
-    if out is not None:
-        results["fig2_curves"] = {str(k): v for k, v in out[2].items()}
+    results["fig2_curves"] = {str(k): v for k, v in out[2].items()}
     section("Table 7 — L2 in FL meta-training", "table_7", T.table_7_l2_in_fl)
 
     print(f"\n# paper-claim validation ({monotonic()-t0:.0f}s)")
@@ -186,6 +182,7 @@ def main(argv=None) -> None:
                              "selection", "faults", "analysis", "obs",
                              "service"])
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     results = {}
     t0 = monotonic()

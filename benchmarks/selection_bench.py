@@ -20,8 +20,9 @@ Compares, on identical data and keys:
                   MAX_BATCHED_ELEMENTS the old engine looped clients
                   one at a time — not the one-stack path that cannot run
                   there at all.
-  sharded(B)      ``select_metadata_sharded`` over a smoke mesh of host
-                  devices (subprocess, XLA_FLAGS device count) — the
+  sharded(B)      ``select_metadata_sharded`` over the process's devices
+                  on an accelerator; on the CPU backend over a smoke mesh
+                  of host devices (subprocess, XLA_FLAGS device count) — the
                   shard_map pod path, selections identical to batched.
                   Smoke-mesh 'devices' are threads on this container's
                   2 cores, so the measured wall cannot show device
@@ -56,6 +57,7 @@ import numpy as np
 from repro.core.selection import (select_metadata, select_metadata_batched,
                                   select_metadata_reference)
 from repro.data import SyntheticActivationMaps
+from repro.launch.mesh import V5E_DEVICE_KIND
 from repro.obs import profile
 from repro.obs.registry import write_bench
 from repro.obs.timing import timeit
@@ -84,7 +86,7 @@ def _roofline_v5e(cost):
     """v5e projection of one fused_fast client from the MEASURED cost
     record (same keys as the old analytic estimate, so the trajectory in
     ``bench_history.jsonl`` stays comparable)."""
-    tp = profile.peak_table("tpu")
+    tp = profile.peak_table(V5E_DEVICE_KIND)
     rf = profile.roofline(cost, tp, dtype="f32")
     return {
         "flops": cost.flops,
@@ -125,13 +127,12 @@ def _indices_md5(sel) -> str:
     return hashlib.md5(np.asarray(sel.indices).tobytes()).hexdigest()
 
 
-def _sharded_worker():
-    """Subprocess entry (own jax init under forced host device count):
-    times ``select_metadata_sharded`` on the same cohort/keys, plus the
-    one-device-mesh serial cost (the isolated-per-device number the /N pod
-    projection uses) and the one-stack batched path in the same env for a
-    like-for-like baseline. Reports the selections' hash for the parent's
-    identity check."""
+def _sharded_measure():
+    """Times ``select_metadata_sharded`` on the same cohort/keys over this
+    process's devices, plus the one-device-mesh serial cost (the
+    isolated-per-device number the /N pod projection uses) and the
+    one-stack batched path in the same process for a like-for-like
+    baseline. Reports the selections' hashes for the identity check."""
     from repro.core.distributed import (select_metadata_sharded,
                                         selection_mesh)
     key = jax.random.PRNGKey(0)
@@ -147,15 +148,22 @@ def _sharded_worker():
         iters=3)
     tb, sb = _time(lambda: select_metadata_batched(
         bacts, blabels, bkeys, pca_solver="randomized", **kw), iters=3)
-    print(json.dumps({"wall_s": t, "devices": len(jax.devices()),
-                      "one_device_wall_s": t1,
-                      "batched_on_mesh_wall_s": tb,
-                      "indices_md5": _indices_md5(s),
-                      "one_device_md5": _indices_md5(s1),
-                      "batched_md5": _indices_md5(sb)}))
+    return {"wall_s": t, "devices": len(jax.devices()),
+            "one_device_wall_s": t1,
+            "batched_on_mesh_wall_s": tb,
+            "indices_md5": _indices_md5(s),
+            "one_device_md5": _indices_md5(s1),
+            "batched_md5": _indices_md5(sb)}
 
 
 def _measure_sharded():
+    """The sharded row. On an accelerator it is measured in this process
+    over the real devices (a child could not open them: this process
+    holds them). On the CPU backend one host device cannot show a mesh,
+    so a child with ``SMOKE_DEVICES`` virtual host devices measures it; a
+    failed child fails the benchmark."""
+    if jax.default_backend() != "cpu":
+        return _sharded_measure()
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count="
                          f"{SMOKE_DEVICES}")
@@ -167,7 +175,8 @@ def _measure_sharded():
          "--sharded-worker"],
         env=env, capture_output=True, text=True, timeout=1200, cwd=REPO)
     if r.returncode != 0:
-        return {"error": r.stderr[-500:]}
+        raise RuntimeError(f"sharded selection worker failed "
+                           f"(rc={r.returncode}): {r.stderr[-500:]}")
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
@@ -201,7 +210,7 @@ def run(out_path: str = "BENCH_selection.json"):
                                      pca_solver="randomized", **kw)
     cost_batch = select_metadata_batched.cost(bacts, blabels, bkeys,
                                               pca_solver="randomized", **kw)
-    peaks = profile.peak_table(jax.default_backend())
+    peaks = profile.peak_table(jax.devices()[0].device_kind)
 
     def cost_fields(cost, wall, nclients=1):
         """Measured flops/bytes (per client) + utilization of the measured
@@ -262,31 +271,30 @@ def run(out_path: str = "BENCH_selection.json"):
                 "throughput_vs_one_stack": t_batch / t_chunk,
                 "selections_match_batched": _indices_md5(s_chunk)
                                             == _indices_md5(s_batch)},
-            "sharded_per_client": (
-                {"error": sharded["error"]} if "error" in sharded else
-                {"wall_s": sharded["wall_s"] / BATCH,
-                 "devices": sharded["devices"],
-                 "batched_on_mesh_wall_s":
-                     sharded["batched_on_mesh_wall_s"] / BATCH,
-                 "one_device_wall_s_per_client":
-                     sharded["one_device_wall_s"] / BATCH,
-                 # the smoke mesh's 'devices' are threads sharing this
-                 # container's physical cores, so the measured wall cannot
-                 # exhibit device parallelism; isolated pod devices each
-                 # run the one-device cost, so per-client wall is /N
-                 "projected_pod_wall_s_per_client":
-                     sharded["one_device_wall_s"]
-                     / (BATCH * sharded["devices"]),
-                 "projected_pod_speedup_vs_batched":
-                     (t_batch / BATCH)
-                     / (sharded["one_device_wall_s"]
-                        / (BATCH * sharded["devices"])),
-                 "speedup_vs_seed":
-                     t_seed / (sharded["wall_s"] / BATCH),
-                 "selections_match_batched":
-                     sharded["indices_md5"] == _indices_md5(s_batch)
-                     and sharded["one_device_md5"]
-                     == _indices_md5(s_batch)}),
+            "sharded_per_client": {
+                "wall_s": sharded["wall_s"] / BATCH,
+                "devices": sharded["devices"],
+                "batched_on_mesh_wall_s":
+                    sharded["batched_on_mesh_wall_s"] / BATCH,
+                "one_device_wall_s_per_client":
+                    sharded["one_device_wall_s"] / BATCH,
+                # the smoke mesh's 'devices' are threads sharing this
+                # container's physical cores, so the measured wall cannot
+                # exhibit device parallelism; isolated pod devices each
+                # run the one-device cost, so per-client wall is /N
+                "projected_pod_wall_s_per_client":
+                    sharded["one_device_wall_s"]
+                    / (BATCH * sharded["devices"]),
+                "projected_pod_speedup_vs_batched":
+                    (t_batch / BATCH)
+                    / (sharded["one_device_wall_s"]
+                       / (BATCH * sharded["devices"])),
+                "speedup_vs_seed":
+                    t_seed / (sharded["wall_s"] / BATCH),
+                "selections_match_batched":
+                    sharded["indices_md5"] == _indices_md5(s_batch)
+                    and sharded["one_device_md5"]
+                    == _indices_md5(s_batch)},
         },
         "roofline_v5e_fused_fast": (
             _roofline_v5e(cost_fast) if cost_fast is not None else
@@ -314,22 +322,18 @@ def run(out_path: str = "BENCH_selection.json"):
          "measured HLO cost, fused_fast path"),
     ]
     sp = report["paths"]["sharded_per_client"]
-    if "error" in sp:
-        rows.append(("selection_sharded_per_client", -1.0,
-                     f"ERROR {sp['error'][:80]}"))
-    else:
-        rows.append(
-            ("selection_sharded_per_client", sp["wall_s"] * 1e3,
-             f"ms devices={sp['devices']} "
-             f"pod_projection={sp['projected_pod_wall_s_per_client']*1e3:.0f}ms "
-             f"({sp['projected_pod_speedup_vs_batched']:.1f}x batched) "
-             f"match={sp['selections_match_batched']}"))
+    rows.append(
+        ("selection_sharded_per_client", sp["wall_s"] * 1e3,
+         f"ms devices={sp['devices']} "
+         f"pod_projection={sp['projected_pod_wall_s_per_client']*1e3:.0f}ms "
+         f"({sp['projected_pod_speedup_vs_batched']:.1f}x batched) "
+         f"match={sp['selections_match_batched']}"))
     return rows, report
 
 
 if __name__ == "__main__":
     if "--sharded-worker" in sys.argv:
-        _sharded_worker()
+        print(json.dumps(_sharded_measure()))
     else:
         rows, _ = run()
         for n, v, e in rows:

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import FLConfig, get_config
 from repro.core import fedavg as fa
 from repro.core.meta_training import meta_train
@@ -19,6 +20,7 @@ from repro.optim import sgd
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("llama3.2-1b").reduced()
     model, lm = make_split_lm(cfg)
     print(f"LM: {cfg.name} (reduced), split at layer {model.split_layer} "

@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import FLConfig, get_wrn_config
 from repro.data import SyntheticImageDataset, partition_k_shards
 from repro.fl.simulation import FLSimulation
@@ -40,6 +41,7 @@ def main():
     ap.add_argument("--out", default="experiments/paper_repro.json")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_wrn_config() if args.full_wrn else get_wrn_config().reduced()
     model = make_split_wrn(cfg)

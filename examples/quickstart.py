@@ -11,6 +11,7 @@ import argparse
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import FLConfig, get_wrn_config
 from repro.data import SyntheticImageDataset, partition_k_shards
 from repro.fl.simulation import FLSimulation
@@ -22,6 +23,7 @@ def main(argv=None):
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="enable observability and write the trace JSONL")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # 1. the paper's model (reduced WRN for CPU) split after group 1
     cfg = get_wrn_config().reduced()
     model = make_split_wrn(cfg)

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.steps import make_decode_step
 from repro.models.transformer import LM
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--cache-len", type=int, default=64)
     ap.add_argument("--tokens", type=int, default=24)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     decode_fn, lm = make_decode_step(cfg, dtype=jnp.float32)

@@ -43,7 +43,7 @@ LAX_HOF = {"jax.lax.map", "lax.map", "jax.lax.scan", "lax.scan",
            "lax.while_loop", "jax.lax.cond", "lax.cond", "jax.lax.switch",
            "lax.switch", "jax.lax.associative_scan", "lax.associative_scan"}
 VMAPPERS = {"jax.vmap", "vmap", "jax.pmap", "pmap", "shard_map",
-            "jax.experimental.shard_map.shard_map"}
+            "jax.shard_map"}
 
 
 def _jit_static_argnames(deco: ast.expr) -> Optional[Set[str]]:
@@ -264,8 +264,8 @@ def _traced_functions(mod: Module):
         fn = dotted_name(node.func) or ""
         is_pallas = fn.endswith("pallas_call")
         is_jit_call = fn in ("jax.jit", "jit")
-        if not (fn in LAX_HOF or fn in VMAPPERS or is_pallas or is_jit_call
-                or fn.split(".")[-1] in ("shard_map",)):
+        if not (fn in LAX_HOF or fn in VMAPPERS or is_pallas
+                or is_jit_call):
             continue
         for arg in node.args[:1] if (is_pallas or is_jit_call) else node.args:
             target, bound = _resolve_fn_arg(arg)

@@ -36,12 +36,10 @@ from typing import Any, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro import obs
 from repro.configs.base import FLConfig
-from repro.core import fedavg as fa
 from repro.core.selection import (Selection, select_metadata,
                                   select_metadata_batched)
 from repro.core.split import SplitModel
@@ -77,7 +75,7 @@ def selection_mesh(num_devices: int = 0) -> Mesh:
     (the production pod meshes live in ``launch/mesh.py``; selection only
     needs the client axis)."""
     n = num_devices or len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def data_axis_size(mesh: Optional[Mesh]) -> int:
@@ -184,9 +182,9 @@ def _select_stack_sharded(model: SplitModel, params: PyTree, xs: jnp.ndarray,
         # ... selection under lax.map (bit-stable for the PCA eigh)
         return acts, jax.lax.map(one, (acts, y, k))
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(), P("data"), P("data"), P("data")),
-                   out_specs=P("data"), check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(), P("data"), P("data"), P("data")),
+                       out_specs=P("data"), check_vma=False)
     return unpad(fn(params, xs, ys, sel_keys))
 
 
@@ -266,17 +264,17 @@ def select_metadata_sharded(acts: jnp.ndarray, labels: Optional[jnp.ndarray],
         return select_metadata(a, y, k, **kwargs)
 
     if labels is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda a, k: jax.lax.map(lambda t: select_metadata(
                 t[0], None, t[1], **kwargs), (a, k)),
             mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=P("data"), check_rep=False)
+            out_specs=P("data"), check_vma=False)
         sels = fn(acts, keys)
     else:
-        fn = shard_map(lambda a, y, k: jax.lax.map(one, (a, y, k)),
-                       mesh=mesh,
-                       in_specs=(P("data"), P("data"), P("data")),
-                       out_specs=P("data"), check_rep=False)
+        fn = jax.shard_map(lambda a, y, k: jax.lax.map(one, (a, y, k)),
+                           mesh=mesh,
+                           in_specs=(P("data"), P("data"), P("data")),
+                           out_specs=P("data"), check_vma=False)
         sels = fn(acts, labels, keys)
     return unpad(sels)
 
@@ -284,6 +282,22 @@ def select_metadata_sharded(acts: jnp.ndarray, labels: Optional[jnp.ndarray],
 # --------------------------------------------------------------------------
 # LocalUpdate over a stacked cohort (§3.2)
 # --------------------------------------------------------------------------
+def _client_update(model: SplitModel, cfg: FLConfig, params: PyTree):
+    """The per-client body the stacked LocalUpdate maps over (x, y, round
+    key): the round key's second half shuffles, exactly as
+    ``rounds.client_round`` derives it. Returns (new params, mean loss)."""
+    # lazy: rounds imports this module
+    from repro.core.rounds import client_local_update
+
+    def one(args):
+        x, y, key = args
+        new_p, losses = client_local_update(model, cfg, params, x, y,
+                                            jax.random.split(key)[1])
+        return new_p, losses.mean()
+
+    return one
+
+
 @profiled_jit(name="local_update_stack", static_argnames=("model", "cfg"))
 def _local_update_stack(model: SplitModel, cfg: FLConfig, params: PyTree,
                         xs: jnp.ndarray, ys: jnp.ndarray, keys: jax.Array):
@@ -291,20 +305,7 @@ def _local_update_stack(model: SplitModel, cfg: FLConfig, params: PyTree,
     (``model``/``cfg`` are frozen dataclasses, so they key the jit cache
     as statics — the profiled wrapper's recompilation sentinel then
     catches any per-round cache miss on the cohort hot path)."""
-    from repro.core.rounds import local_batches  # lazy: rounds imports us
-    from repro.optim import sgd
-    opt = sgd(cfg.local_lr)
-
-    def one(args):
-        x, y, key = args
-        k_loc = jax.random.split(key)[1]
-        bx, by = local_batches(x, y, k_loc, cfg)
-        new_p, _, losses = fa.local_update(
-            params, opt, opt.init(params), (bx, by),
-            lambda p, b: model.loss(p, b))
-        return new_p, losses.mean()
-
-    return jax.lax.map(one, (xs, ys, keys))
+    return jax.lax.map(_client_update(model, cfg, params), (xs, ys, keys))
 
 
 def local_update_cohort(model: SplitModel, params: PyTree, xs: jnp.ndarray,
@@ -324,24 +325,13 @@ def local_update_cohort(model: SplitModel, params: PyTree, xs: jnp.ndarray,
     keys = jnp.asarray(keys)
 
     if data_axis_size(mesh) > 1:
-        from repro.core.rounds import local_batches  # lazy: rounds imports us
-        from repro.optim import sgd
-        opt = sgd(cfg.local_lr)
-
-        def one(args):
-            x, y, key = args
-            k_loc = jax.random.split(key)[1]
-            bx, by = local_batches(x, y, k_loc, cfg)
-            new_p, _, losses = fa.local_update(
-                params, opt, opt.init(params), (bx, by),
-                lambda p, b: model.loss(p, b))
-            return new_p, losses.mean()
-
+        one = _client_update(model, cfg, params)
         (xs, ys, keys), unpad = _pad_clients((xs, ys, keys),
                                              data_axis_size(mesh))
-        fn = shard_map(lambda x, y, k: jax.lax.map(one, (x, y, k)),
-                       mesh=mesh, in_specs=(P("data"), P("data"), P("data")),
-                       out_specs=P("data"), check_rep=False)
+        fn = jax.shard_map(lambda x, y, k: jax.lax.map(one, (x, y, k)),
+                           mesh=mesh,
+                           in_specs=(P("data"), P("data"), P("data")),
+                           out_specs=P("data"), check_vma=False)
         return unpad(fn(xs, ys, keys))
 
     return _local_update_stack(model, cfg, params, xs, ys, keys)

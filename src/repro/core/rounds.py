@@ -36,6 +36,7 @@ from repro.core.selection import Selection, select_metadata
 from repro.core.split import SplitModel
 from repro.data.partition import ClientData
 from repro.fl.comms import CommLedger
+from repro.obs.profile import profiled_jit
 from repro.optim import sgd
 
 PyTree = Any
@@ -159,6 +160,27 @@ def local_batches(x: jnp.ndarray, y: jnp.ndarray, k_loc: jax.Array,
     return bx, by
 
 
+def client_local_update(model: SplitModel, cfg: FLConfig, params: PyTree,
+                        x: jnp.ndarray, y: jnp.ndarray, k_loc: jax.Array):
+    """One client's LocalUpdate (§3.2) from the global ``params``: shuffle
+    and batch with ``k_loc``, then the SGD scan. Returns (new params,
+    per-step losses). The sequential ``client_round`` runs it compiled
+    (``_local_update_client``); the stacked engines map it over clients."""
+    bx, by = local_batches(x, y, k_loc, cfg)
+    opt = sgd(cfg.local_lr)
+    new_params, _, losses = fa.local_update(
+        params, opt, opt.init(params), (bx, by),
+        lambda p, b: model.loss(p, b))
+    return new_params, losses
+
+
+# one compiled LocalUpdate shared by every client and round whose shapes
+# agree (an eager scan traces and compiles the model's SGD loop per call)
+_local_update_client = profiled_jit(client_local_update,
+                                    name="local_update_client",
+                                    static_argnames=("model", "cfg"))
+
+
 def client_round(model: SplitModel, params: PyTree, client: ClientData,
                  cfg: FLConfig, key: jax.Array, ledger: CommLedger,
                  num_classes: int, precomputed=None, channel=None,
@@ -227,14 +249,11 @@ def client_round(model: SplitModel, params: PyTree, client: ClientData,
 
         # ---- LocalUpdate ----
         with obs.span("local_update") as lsp:
-            bx, by = local_batches(x, y, k_loc, cfg)
-            opt = sgd(cfg.local_lr)
-            new_params, _, losses = fa.local_update(
-                params, opt, opt.init(params), (bx, by),
-                lambda p, b: model.loss(p, b))
+            new_params, losses = _local_update_client(model, cfg, params,
+                                                      x, y, k_loc)
             lsp.sync(new_params)
             if lsp.enabled:
-                lsp.set(steps=int(bx.shape[0]))
+                lsp.set(steps=int(losses.shape[0]))
         channel.upload_update(client_id, new_params)
         if csp.enabled:
             csp.set(samples=int(x.shape[0]))

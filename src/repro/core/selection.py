@@ -56,19 +56,35 @@ class PCAState(NamedTuple):
     explained: jnp.ndarray     # (P,) eigenvalues
 
 
+def _eigh(a: jnp.ndarray):
+    """Symmetric eigendecomposition -> (ascending eigenvalues, eigenvector
+    columns), exact on every backend. On a TPU it is XLA's native Jacobi
+    ``Eigh``: JAX's default TPU lowering (QDWH spectral divide and conquer,
+    traced into the program) takes minutes to compile for the 2500 x 2500
+    Gram of one client's maps. Elsewhere it is the backend's default
+    (LAPACK on the CPU)."""
+    def jacobi(m):
+        v, w = jax.lax.linalg.eigh(
+            m, implementation=jax.lax.linalg.EighImplementation.JACOBI)
+        return w, v
+
+    return jax.lax.platform_dependent(
+        a, tpu=jacobi, default=lambda m: tuple(jnp.linalg.eigh(m)))
+
+
 def _pca_exact(xc: jnp.ndarray, cnt: jnp.ndarray, p: int):
     """Exact top-p eigenpairs: Gram trick when N <= D, else covariance."""
     n, d = xc.shape
     if n <= d:
         g = (xc @ xc.T) / cnt                       # (N, N) Gram
-        evals, evecs = jnp.linalg.eigh(g)           # ascending
+        evals, evecs = _eigh(g)                     # ascending
         evals, evecs = evals[::-1][:p], evecs[:, ::-1][:, :p]
         safe = jnp.sqrt(jnp.maximum(evals * cnt, 1e-12))
         comps = (xc.T @ evecs) / safe               # (D, P) unit-norm cols
         comps = comps.T
     else:
         cov = (xc.T @ xc) / cnt                     # (D, D)
-        evals, evecs = jnp.linalg.eigh(cov)
+        evals, evecs = _eigh(cov)
         evals, evecs = evals[::-1][:p], evecs[:, ::-1][:, :p]
         comps = evecs.T
     return evals, comps
@@ -100,7 +116,7 @@ def _pca_randomized(xc: jnp.ndarray, cnt: jnp.ndarray, p: int,
     q, _ = jnp.linalg.qr(q)                          # (D, l) orthonormal
     b = xc @ q                                       # (N, l)
     small = (b.T @ b) / cnt                          # (l, l) = Q^T C Q
-    evals, evecs = jnp.linalg.eigh(small)
+    evals, evecs = _eigh(small)
     evals, evecs = evals[::-1][:p], evecs[:, ::-1][:, :p]
     comps = (q @ evecs).T                            # (P, D)
     return evals, comps, b, evecs

@@ -35,8 +35,8 @@ fused kernel, in f32 words:
     lmask    block_n * K
     sums     K * D          (resident accumulator)
     counts   K
-    assign   block_n        (int32)
-    mindist  block_n
+    assign   block_n        (int32, a (block_n, 1) column)
+    mindist  block_n        (a (block_n, 1) column)
     + the (block_n, K) distance / one-hot intermediates.
 
 At the paper-scale operating point (block_n=256, D=128, K=128 after
@@ -101,8 +101,8 @@ def _kmeans_lloyd_kernel(x_ref, c_ref, m_ref,
     d = x2 + c2[None, :] - 2.0 * xc + lm                   # biased distances
 
     assign = jnp.argmin(d, axis=1).astype(jnp.int32)
-    assign_ref[...] = assign
-    mind_ref[...] = jnp.min(d, axis=1)
+    assign_ref[...] = assign[:, None]
+    mind_ref[...] = jnp.min(d, axis=1, keepdims=True)
 
     # a row with no admissible cluster (min mask > 0) gets zero weight
     w = (jnp.min(lm, axis=1) <= 0.0).astype(jnp.float32)   # (block_n,)
@@ -128,8 +128,10 @@ def kmeans_lloyd_kernel(x: jnp.ndarray, c: jnp.ndarray, lmask: jnp.ndarray,
                         block_n: int = 256, interpret: bool = False):
     """Fused Lloyd step. x: (N, D) f32, c: (K, D) f32, lmask: (N, K) f32
     additive mask, N % block_n == 0, D/K lane-aligned (ops.kmeans_lloyd_step
-    handles padding). Returns (assign (N,) i32, mindist (N,) f32,
-    sums (K, D) f32, counts (1, K) f32)."""
+    handles padding). Returns (assign (N, 1) i32, mindist (N, 1) f32,
+    sums (K, D) f32, counts (1, K) f32). The per-row outputs are columns,
+    the layout a lane reduction produces: Mosaic refuses 1-D (block_n,)
+    blocks against XLA's 1-D tiling unless block_n is a multiple of 1024."""
     n, d = x.shape
     k = c.shape[0]
     assert n % block_n == 0, (n, block_n)
@@ -144,14 +146,14 @@ def kmeans_lloyd_kernel(x: jnp.ndarray, c: jnp.ndarray, lmask: jnp.ndarray,
             pl.BlockSpec((block_n, k), lambda i: (i, 0)),   # stream mask blocks
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),         # accumulator
             pl.BlockSpec((1, k), lambda i: (0, 0)),         # accumulator
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((k, d), jnp.float32),
             jax.ShapeDtypeStruct((1, k), jnp.float32),
         ],

@@ -1,5 +1,6 @@
 """jit'd public wrappers around the Pallas kernels: shape padding/alignment,
-CPU interpret-mode fallback (this container), and the dispatch points the
+interpret mode off the TPU backend (the CPU test runs; on a TPU the
+kernels always compile through Mosaic), and the dispatch points the
 model/selection code calls.
 
 Each dispatch site sits in an ``obs.timed_block`` span (a no-op when
@@ -32,12 +33,16 @@ _pdist = profiled_jit(kmeans_pairwise_dist_kernel,
 _lloyd = profiled_jit(kmeans_lloyd_kernel, name="kmeans_lloyd_kernel",
                       static_argnames=("block_n", "interpret"))
 _quant = profiled_jit(quantize_affine_kernel, name="quantize_affine_kernel",
-                      static_argnames=("d_true", "block_n", "interpret"))
+                      static_argnames=("d_true", "block_n", "block_d",
+                                       "interpret"))
 _flash = profiled_jit(flash_attention_kernel, name="flash_attention_kernel",
                       static_argnames=("causal", "window", "block_q",
                                        "block_k", "interpret"))
 _decode = profiled_jit(flash_decode_kernel, name="flash_decode_kernel",
                        static_argnames=("block_s", "interpret"))
+
+
+QUANT_BLOCK_D = 2048
 
 
 def _interpret() -> bool:
@@ -91,28 +96,35 @@ def kmeans_lloyd_step(x: jnp.ndarray, c: jnp.ndarray, lmask: jnp.ndarray,
     with obs.timed_block("kernel.kmeans_lloyd_step", n=n, d=d, k=k) as sp:
         assign, mind, sums, counts = sp.sync(_lloyd(
             xp, cp, lp, block_n=block_n, interpret=_interpret()))
-    return assign[:n], mind[:n], sums[:k, :d], counts[0, :k]
+    return assign[:n, 0], mind[:n, 0], sums[:k, :d], counts[0, :k]
 
 
 def quantize_affine(x: jnp.ndarray, rowmask: jnp.ndarray,
                     block_n: int = 256):
     """Per-tensor affine int8 quantization of (N, D) x with (N,) row mask
-    (the transport codec's pack hot path). Pads N to block_n and D to lane
-    width 128; padded rows are masked out of the statistics and padded
-    columns are guarded by the kernel's static d_true, so padding is
-    correctness-free. Returns (q (N, D) int8, xmin f32, scale f32) exactly
-    matching ``ref.quantize_affine_ref`` bit-for-bit (vmappable across a
-    stacked cohort — the batch axis becomes the outermost grid dim)."""
+    (the transport codec's pack hot path). Pads N to a multiple of the row
+    tile (``block_n``, shrunk to the 32-row int8 packing for short
+    payloads) and D to lane width 128; the column tile is the widest
+    multiple of 128 up to ``QUANT_BLOCK_D`` that divides the padded D (a
+    (256, 2048) f32 tile is 2 MiB, so the kernel fits VMEM at any D).
+    Padded rows are masked out of the statistics and padded columns are guarded
+    by the kernel's static d_true, so padding is correctness-free. Returns
+    (q (N, D) int8, xmin f32, scale f32) exactly matching
+    ``ref.quantize_affine_ref`` bit-for-bit (vmappable across a stacked
+    cohort — the batch axis becomes the outermost grid dim)."""
     n, d = x.shape
     if n < 64:   # tiny payloads: the jnp path beats kernel dispatch
         return ref.quantize_affine_ref(x, rowmask)
-    npad = _pad_to(n, block_n)
+    bn = min(block_n, _pad_to(n, 32))
+    npad = _pad_to(n, bn)
     dpad = _pad_to(d, 128)
+    bd = max(b for b in range(128, min(QUANT_BLOCK_D, dpad) + 1, 128)
+             if dpad % b == 0)
     xp = jnp.pad(x.astype(jnp.float32), ((0, npad - n), (0, dpad - d)))
     mp = jnp.pad(rowmask.astype(jnp.float32), (0, npad - n))
     mp = jnp.broadcast_to(mp[:, None], (npad, 128))
     with obs.timed_block("kernel.quantize_affine", n=n, d=d) as sp:
-        q, mm = sp.sync(_quant(xp, mp, d_true=d, block_n=block_n,
+        q, mm = sp.sync(_quant(xp, mp, d_true=d, block_n=bn, block_d=bd,
                                interpret=_interpret()))
     xmin, scale = ref.affine_params_from_minmax(mm[0, 0], mm[1, 0])
     return q[:n, :d], xmin, scale

@@ -191,7 +191,8 @@ def roofline_terms(rec: dict, tcfg: TrainConfig) -> dict:
     chips = rec["chips"]
     crec = profile.record_from_dryrun(rec)
     flops_dev = crec.flops
-    terms = profile.roofline(crec, profile.peak_table("tpu"), dtype="bf16")
+    peaks = profile.peak_table(mesh_lib.V5E_DEVICE_KIND)
+    terms = profile.roofline(crec, peaks, dtype="bf16")
     # MODEL_FLOPS: 6*N_active*D train (D = tokens this step), 2*N*D decode
     toks = rec["global_batch"] * (rec["seq_len"] if rec["kind"] != "decode"
                                   else 1)

@@ -46,6 +46,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     import jax  # noqa: F401  (force the backend up before timing)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import FLConfig, get_wrn_config
     from repro.data import SyntheticImageDataset, partition_k_shards
     from repro.fl.faults import FaultPlan

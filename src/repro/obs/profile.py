@@ -99,25 +99,30 @@ def record_from_dryrun(rec: Dict[str, Any]) -> CostRecord:
 
 
 # --------------------------------------------------------------------------
-# per-backend peak table
+# per-device peak table
 # --------------------------------------------------------------------------
-# Host-CPU peaks are order-of-magnitude estimates (a couple of AVX cores)
-# — good enough for *relative* utilization trajectories on this container;
-# the TPU entry is the v5e datasheet via launch/mesh.py (single source).
+# Host-CPU peaks are order-of-magnitude estimates (a couple of AVX cores),
+# kept for the CPU test runs; the TPU entry is the v5e datasheet via
+# launch/mesh.py (single source), under the kind the chip reports.
 _CPU_PEAKS = {"peak_flops_bf16": 2.0e11, "peak_flops_f32": 1.0e11,
               "hbm_bw": 2.0e10, "ici_bw": 0.0}
 
 
-def peak_table(backend: str) -> Dict[str, float]:
-    """Peak FLOP/s and memory bandwidth for ``backend`` ('tpu'/'cpu'/...).
-    The selection/kernels pipelines compute in f32, so span utilization
-    uses ``peak_flops_f32``; the LM dry-run rooflines use bf16."""
-    if backend == "tpu":
-        from repro.launch import mesh
+def peak_table(device_kind: str) -> Dict[str, float]:
+    """Peak FLOP/s and memory bandwidth for a device, keyed by its
+    ``device_kind`` (``jax.devices()[0].device_kind``: ``"cpu"`` or the
+    v5e's ``launch.mesh.V5E_DEVICE_KIND``). The selection/kernels pipelines
+    compute in f32, so span utilization uses ``peak_flops_f32``; the LM
+    dry-run rooflines use bf16. A kind with no entry raises: a guessed
+    peak would make every utilization figure wrong."""
+    from repro.launch import mesh
+    if device_kind == "cpu":
+        return dict(_CPU_PEAKS)
+    if device_kind == mesh.V5E_DEVICE_KIND:
         return {"peak_flops_bf16": mesh.PEAK_FLOPS_BF16,
                 "peak_flops_f32": mesh.PEAK_FLOPS_BF16 / 2,
                 "hbm_bw": mesh.HBM_BW, "ici_bw": mesh.ICI_BW}
-    return dict(_CPU_PEAKS)
+    raise ValueError(f"no peak table for device kind {device_kind!r}")
 
 
 def roofline(cost: CostRecord, peaks: Dict[str, float],
@@ -215,6 +220,12 @@ class ProfiledFunction:
         self._costs[sig] = cost
         return cost
 
+    def lower(self, *args: Any, **kwargs: Any) -> Any:
+        """``jax.jit(fn).lower(...)`` of the one underlying jitted callable:
+        the program a call with these arguments (arrays or
+        ``jax.ShapeDtypeStruct``s) compiles."""
+        return self._jit().lower(*args, **kwargs)
+
     def cost(self, *args: Any, **kwargs: Any) -> Optional[CostRecord]:
         """The :class:`CostRecord` this call signature would compile to
         (derives + caches on first use; no tracer required — benchmarks
@@ -249,7 +260,7 @@ class ProfiledFunction:
         cur = tracer.current()
         if cur is not None and cost is not None:
             import jax
-            peaks = peak_table(jax.default_backend())
+            peaks = peak_table(jax.devices()[0].device_kind)
             # accumulate: one span may cover several profiled calls
             # (chunked cohorts); the span computes utilization on close
             cur.attrs["flops"] = cur.attrs.get("flops", 0.0) + cost.flops

@@ -1,5 +1,4 @@
 import os
-import sys
 
 # Tests see ONE device (never set the 512-device dry-run flag globally);
 # dry-run smoke tests spawn subprocesses with their own XLA_FLAGS.
@@ -7,64 +6,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np
 import pytest
-
-
-def _install_hypothesis_shim():
-    """Minimal stand-in for the slice of the hypothesis API these tests use
-    (``@settings``/``@given`` + ``strategies.integers``) so the suite collects
-    on machines without the dependency. Property tests still run, as seeded
-    random sweeps drawn from the declared strategies."""
-    import functools
-    import inspect
-    import random
-    import types
-
-    mod = types.ModuleType("hypothesis")
-    strategies = types.ModuleType("hypothesis.strategies")
-
-    class _Integers:
-        def __init__(self, min_value, max_value):
-            self.min_value, self.max_value = min_value, max_value
-
-        def sample(self, rng):
-            return rng.randint(self.min_value, self.max_value)
-
-    def integers(min_value, max_value):
-        return _Integers(min_value, max_value)
-
-    def settings(max_examples=10, **_ignored):
-        def deco(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    def given(**strats):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_shim_max_examples", 10)
-                rng = random.Random(0)
-                for _ in range(n):
-                    draw = {k: s.sample(rng) for k, s in strats.items()}
-                    fn(*args, **draw, **kwargs)
-            # hide the strategy params from pytest's fixture resolution
-            # (real hypothesis does the same via a zero-arg signature)
-            del wrapper.__wrapped__
-            wrapper.__signature__ = inspect.Signature()
-            return wrapper
-        return deco
-
-    mod.given, mod.settings, mod.strategies = given, settings, strategies
-    mod.__version__ = "0.0.0-shim"
-    strategies.integers = integers
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strategies
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_shim()
 
 
 @pytest.fixture(scope="session")

@@ -97,6 +97,18 @@ class TestProfiledJit:
                               "bound"}
         assert terms["bound"] in ("compute", "memory", "collective")
 
+    def test_peak_table_v5e_from_mesh_constants(self):
+        from repro.launch import mesh
+        peaks = profile.peak_table(mesh.V5E_DEVICE_KIND)
+        assert peaks["peak_flops_bf16"] == mesh.PEAK_FLOPS_BF16
+        assert peaks["hbm_bw"] == mesh.HBM_BW
+        assert peaks["ici_bw"] == mesh.ICI_BW
+
+    @pytest.mark.parametrize("kind", ["tpu", "TPU v4", "TPU v6 lite", ""])
+    def test_peak_table_unknown_kind_raises(self, kind):
+        with pytest.raises(ValueError, match="no peak table"):
+            profile.peak_table(kind)
+
     def test_record_from_dryrun_roundtrip(self):
         rec = {"cost": {"flops_expanded": 5.0, "bytes_expanded": 7.0},
                "collectives": {"total_bytes": 3.0,
