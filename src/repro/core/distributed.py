@@ -303,8 +303,8 @@ def _local_update_stack(model: SplitModel, cfg: FLConfig, params: PyTree,
                         xs: jnp.ndarray, ys: jnp.ndarray, keys: jax.Array):
     """The single-host stacked LocalUpdate as one compiled function
     (``model``/``cfg`` are frozen dataclasses, so they key the jit cache
-    as statics — the profiled wrapper's recompilation sentinel then
-    catches any per-round cache miss on the cohort hot path)."""
+    as statics — a per-round cache miss on the cohort hot path then
+    shows as a ``compile`` span in every traced round)."""
     return jax.lax.map(_client_update(model, cfg, params), (xs, ys, keys))
 
 

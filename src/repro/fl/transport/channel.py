@@ -126,8 +126,9 @@ class Channel:
         (int8 quantize runs once, vmapped, over the whole stack), charge
         each frame's exact bytes, and return the per-client decoded
         triples (None per client whose frame was lost)."""
-        pres = prequantize_cohort(codec, jnp.asarray(sel_acts),
-                                  jnp.asarray(valid))
+        with obs.span("encode", frame="knowledge", clients=len(client_ids)):
+            pres = prequantize_cohort(codec, jnp.asarray(sel_acts),
+                                      jnp.asarray(valid))
         out = []
         for i, cid in enumerate(client_ids):
             out.append(self.upload_knowledge(

@@ -23,10 +23,8 @@ from repro.kernels.kmeans import kmeans_lloyd_kernel, kmeans_pairwise_dist_kerne
 from repro.kernels.quantize import quantize_affine_kernel
 from repro.obs.profile import profiled_jit
 
-# profiled entries (module-level, so the signature caches live across
-# rounds — the recompilation sentinel counts every *new* signature, and
-# traced eager dispatches get {flops, hbm_bytes, utilization} attached to
-# the enclosing kernel.* span)
+# profiled entries (module-level, so jax's jit caches live across rounds
+# and a traced run's compile spans show only new signatures)
 _pdist = profiled_jit(kmeans_pairwise_dist_kernel,
                       name="kmeans_pairwise_dist_kernel",
                       static_argnames=("block_n", "interpret"))

@@ -11,7 +11,9 @@ difference / regress found a regression; 2 usage or unreadable input.
 ``diff`` compares structure, not wall time (two runs never agree on
 nanoseconds): span counts and ledger bytes per span path, event counts
 per name, and metrics counters — exactly the signals that must not move
-when a change claims to be byte- and shape-neutral.
+when a change claims to be byte- and shape-neutral. JAX's compile work
+(``compile`` spans, ``compile.*``/``compile_s.*`` counters) counts as
+timing: a cold and a warm compile cache give the same structure.
 
 ``regress`` compares current BENCH_*.json files (default: all of them
 under ``benchmarks/``) against the append-only run history written by
@@ -31,7 +33,8 @@ import sys
 from typing import Any, Dict
 
 from repro.obs import registry
-from repro.obs.tracer import TraceError, load_trace, span_paths, to_chrome
+from repro.obs.tracer import (COMPILE, TraceError, is_timing_counter,
+                              load_trace, span_path, span_paths, to_chrome)
 
 
 def _load(path: str) -> Dict[str, Any]:
@@ -52,22 +55,19 @@ def cmd_summarize(args: argparse.Namespace) -> int:
     print(f"spans    {len(tr['spans'])}")
     print(f"events   {len(tr['events'])}")
     paths = span_paths(tr)
+    by_id = {sp["id"]: sp for sp in tr["spans"]}
     if paths:
         t_by_path = {p: 0.0 for p in paths}
-        by_id = {sp["id"]: sp for sp in tr["spans"]}
         for sp in tr["spans"]:
-            parts = [sp["name"]]
-            pid = sp.get("parent")
-            while pid in by_id:
-                parts.append(by_id[pid]["name"])
-                pid = by_id[pid].get("parent")
-            t_by_path["/".join(reversed(parts))] += sp["t1"] - sp["t0"]
+            if sp["name"] != COMPILE:
+                t_by_path[span_path(by_id, sp)] += sp["t1"] - sp["t0"]
         width = max(len(p) for p in paths)
         print(f"{'span path'.ljust(width)}  count     wall_s        bytes")
         for p in sorted(paths):
             s = paths[p]
             print(f"{p.ljust(width)}  {s['count']:5d}  {t_by_path[p]:9.4f}"
                   f"  {s['bytes']:11d}")
+    _print_compiles(tr, by_id)
     snap = tr["metrics"].get("snapshot", {})
     for kind in ("counters", "gauges"):
         for name, v in sorted(snap.get(kind, {}).items()):
@@ -76,6 +76,31 @@ def cmd_summarize(args: argparse.Namespace) -> int:
     if any(unattr.values()):
         print(f"WARNING: unattributed ledger bytes: {unattr}")
     return 0
+
+
+def _print_compiles(tr: Dict[str, Any], by_id: Dict[int, Any]) -> None:
+    """Compile seconds per phase, and backend compiles, by the path of
+    the span each ``compile`` span fell under."""
+    rows: Dict[str, Dict[str, float]] = {}
+    for sp in tr["spans"]:
+        if sp["name"] != COMPILE:
+            continue
+        parent = by_id.get(sp.get("parent"))
+        path = span_path(by_id, parent) if parent else "(no span)"
+        row = rows.setdefault(path, {"trace": 0.0, "lower": 0.0,
+                                     "backend": 0.0, "n": 0})
+        phase = sp.get("attrs", {}).get("phase", "backend")
+        row[phase] = row.get(phase, 0.0) + sp["t1"] - sp["t0"]
+        row["n"] += phase == "backend"
+    if not rows:
+        return
+    width = max(len(p) for p in rows)
+    print(f"{'compile under'.ljust(width)}  backends   trace_s   lower_s"
+          f" backend_s")
+    for p in sorted(rows):
+        r = rows[p]
+        print(f"{p.ljust(width)}  {int(r['n']):8d}  {r['trace']:8.4f}"
+              f"  {r['lower']:8.4f}  {r['backend']:8.4f}")
 
 
 def cmd_export_chrome(args: argparse.Namespace) -> int:
@@ -101,7 +126,8 @@ def _diff_dicts(label: str, a: Dict[str, Any], b: Dict[str, Any]) -> int:
 
 def cmd_diff(args: argparse.Namespace) -> int:
     """``diff``: structural comparison of two traces (span paths, event
-    counts, counters, unattributed bytes); exit 1 on any difference."""
+    counts, counters, unattributed bytes; compile work left out); exit 1
+    on any difference."""
     ta, tb = _load(args.a), _load(args.b)
     diffs = 0
     pa, pb = span_paths(ta), span_paths(tb)
@@ -114,9 +140,12 @@ def cmd_diff(args: argparse.Namespace) -> int:
         return out
 
     diffs += _diff_dicts("events", ev_counts(ta), ev_counts(tb))
-    diffs += _diff_dicts(
-        "counter", ta["metrics"].get("snapshot", {}).get("counters", {}),
-        tb["metrics"].get("snapshot", {}).get("counters", {}))
+
+    def counters(tr: Dict[str, Any]) -> Dict[str, Any]:
+        snap = tr["metrics"].get("snapshot", {}).get("counters", {})
+        return {k: v for k, v in snap.items() if not is_timing_counter(k)}
+
+    diffs += _diff_dicts("counter", counters(ta), counters(tb))
     diffs += _diff_dicts("unattributed",
                          ta["metrics"].get("unattributed", {}),
                          tb["metrics"].get("unattributed", {}))

@@ -1,24 +1,15 @@
-"""repro.obs.profile — cost-annotated spans + the recompilation sentinel.
+"""repro.obs.profile — ``profiled_jit`` and the offline cost record.
 
 ``profiled_jit`` wraps ``jax.jit`` for the runtime's compiled hot paths
 (the §3.1 selection pipeline, the Pallas kernel entries, the stacked
-LocalUpdate).  With a tracer active, every *new* call signature
+LocalUpdate). A call is the one underlying jitted callable, traced or
+not; what a call compiles shows in a traced run as ``compile`` spans,
+taken from JAX's own compile events (``repro.obs.compiles``).
 
-  * bumps the ``compile.<name>`` / ``compile.<name>.<sig>`` counters in
-    the tracer's ``MetricsRegistry`` and records a ``compile`` event
-    under the open span — the **recompilation sentinel**: a
-    retrace-per-round bug shows up as compile events parented to
-    ``round > 0`` spans, which ``benchmarks/obs_bench.py`` asserts never
-    happens (``zero_hot_path_recompiles_after_round_0``);
-  * derives a :class:`CostRecord` from the compiled module's HLO text —
-    ``launch/hlo_analysis.py`` is the repo's ONE FLOP/byte deriver and
-    this module is its façade — and attaches ``flops``/``hbm_bytes``
-    (plus the per-backend peaks, from which the closing span computes
-    ``utilization``) to the enclosing ``kernel.*``/``select`` span.
-
-With no tracer active (``FLConfig.observability`` off) the wrapper is a
-plain ``jax.jit`` call behind one attribute read — bit-identical runs,
-zero profiling work, exactly the NullTracer contract.
+Offline, ``ProfiledFunction.cost`` derives a :class:`CostRecord` from the
+compiled module's HLO text — ``launch/hlo_analysis.py`` is the repo's ONE
+FLOP/byte deriver and this module is its façade — for the dry-run and
+benchmark rooflines (``roofline``, ``peak_table``).
 
 Import-safe without jax: jax, ``launch.hlo_analysis`` and ``launch.mesh``
 are only imported lazily inside calls (the flcheck CI job imports
@@ -26,12 +17,9 @@ are only imported lazily inside calls (the flcheck CI job imports
 """
 from __future__ import annotations
 
-import hashlib
 import inspect
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
-
-from repro.obs.tracer import get_tracer
 
 
 # --------------------------------------------------------------------------
@@ -52,7 +40,7 @@ class CostRecord:
     unknown_trip_loops: int = 0
 
     def as_dict(self) -> Dict[str, float]:
-        """Plain-dict form for span attrs / JSON reports."""
+        """Plain-dict form for JSON reports."""
         return {"flops": self.flops, "hbm_bytes": self.hbm_bytes,
                 "transcendentals": self.transcendentals,
                 "collective_bytes": self.collective_bytes,
@@ -72,8 +60,8 @@ def record_from_hlo(hc: Any) -> CostRecord:
 
 def cost_from_hlo_text(text: str) -> CostRecord:
     """The repo's single FLOP deriver: ``launch/hlo_analysis.parse_hlo``
-    re-exposed as a :class:`CostRecord` (dry-run, roofline tables and the
-    profiled spans all route through here)."""
+    re-exposed as a :class:`CostRecord` (dry-run, roofline tables and
+    ``ProfiledFunction.cost`` all route through here)."""
     from repro.launch.hlo_analysis import parse_hlo
     return record_from_hlo(parse_hlo(text))
 
@@ -86,7 +74,7 @@ def cost_from_compiled(compiled: Any) -> CostRecord:
 def record_from_dryrun(rec: Dict[str, Any]) -> CostRecord:
     """Rebuild the cost record from a saved dry-run JSON (``launch/dryrun``
     output) so ``benchmarks/roofline_report.py`` renders from the same
-    record type the live profiler attaches."""
+    record type ``ProfiledFunction.cost`` derives."""
     cost = rec.get("cost", {})
     coll = rec.get("collectives", {})
     return CostRecord(
@@ -111,10 +99,10 @@ _CPU_PEAKS = {"peak_flops_bf16": 2.0e11, "peak_flops_f32": 1.0e11,
 def peak_table(device_kind: str) -> Dict[str, float]:
     """Peak FLOP/s and memory bandwidth for a device, keyed by its
     ``device_kind`` (``jax.devices()[0].device_kind``: ``"cpu"`` or the
-    v5e's ``launch.mesh.V5E_DEVICE_KIND``). The selection/kernels pipelines
-    compute in f32, so span utilization uses ``peak_flops_f32``; the LM
-    dry-run rooflines use bf16. A kind with no entry raises: a guessed
-    peak would make every utilization figure wrong."""
+    v5e's ``launch.mesh.V5E_DEVICE_KIND``). The LM dry-run rooflines use
+    bf16; ``peak_flops_f32`` is read by ``benchmarks/selection_bench.py``
+    alone. A kind with no entry raises: a guessed peak would make every
+    utilization figure wrong."""
     from repro.launch import mesh
     if device_kind == "cpu":
         return dict(_CPU_PEAKS)
@@ -151,10 +139,9 @@ def _abstract(leaf: Any) -> str:
 
 
 class ProfiledFunction:
-    """``jax.jit`` plus the sentinel/cost layer.  Execution always goes
+    """``jax.jit`` plus the offline cost layer.  Execution always goes
     through the one underlying jitted callable (so traced and untraced
-    runs share jax's dispatch cache and stay bit-identical); profiling is
-    bookkeeping around it, active only under a live tracer."""
+    runs share jax's dispatch cache and stay bit-identical)."""
 
     def __init__(self, fn: Callable, *, name: Optional[str] = None,
                  static_argnames: Tuple[str, ...] = ()) -> None:
@@ -166,7 +153,6 @@ class ProfiledFunction:
         self._jitted: Any = None
         self._pysig: Any = None
         self._costs: Dict[str, Optional[CostRecord]] = {}
-        self._counted: set = set()
 
     # -- lazy jax plumbing -------------------------------------------
     def _jit(self) -> Any:
@@ -178,8 +164,8 @@ class ProfiledFunction:
 
     def signature_key(self, args: tuple, kwargs: dict) -> str:
         """Abstract call signature: (shape, dtype) per array leaf, repr
-        for statics — mirrors jax's jit cache key closely enough that a
-        new key here means jax compiled."""
+        for statics — mirrors jax's jit cache key closely enough to key
+        the cost records."""
         import jax
         if self._pysig is None:
             try:
@@ -202,10 +188,6 @@ class ProfiledFunction:
         parts.append(str(treedef))
         parts.append(repr(sorted((k, repr(v)) for k, v in static.items())))
         return "|".join(parts)
-
-    @staticmethod
-    def _sig_hash(sig: str) -> str:
-        return hashlib.md5(sig.encode()).hexdigest()[:10]
 
     def _derive_cost(self, sig: str, args: tuple,
                      kwargs: dict) -> Optional[CostRecord]:
@@ -233,50 +215,14 @@ class ProfiledFunction:
         return self._derive_cost(self.signature_key(args, kwargs),
                                  args, kwargs)
 
-    # -- the call ----------------------------------------------------
     def __call__(self, *args: Any, **kwargs: Any) -> Any:
-        tracer = get_tracer()
-        if not tracer.enabled:
-            return self._jit()(*args, **kwargs)
-        from repro.obs.tracer import _has_jax_tracer
-        if _has_jax_tracer((args, kwargs)):
-            # inside an enclosing trace: this call inlines into the outer
-            # computation — its compile belongs to the outer function
-            return self._jit()(*args, **kwargs)
-
-        sig = self.signature_key(args, kwargs)
-        if sig not in self._counted:
-            # the sentinel: jax compiles exactly when it first sees this
-            # signature, so count it where the trace can see the round
-            self._counted.add(sig)
-            h = self._sig_hash(sig)
-            tracer.metrics.counter(f"compile.{self.name}").inc()
-            tracer.metrics.counter(f"compile.{self.name}.{h}").inc()
-            tracer.event("compile", fn=self.name, signature=h,
-                         nth=len(self._counted))
-        cost = self._derive_cost(sig, args, kwargs)
-        out = self._jit()(*args, **kwargs)
-
-        cur = tracer.current()
-        if cur is not None and cost is not None:
-            import jax
-            peaks = peak_table(jax.devices()[0].device_kind)
-            # accumulate: one span may cover several profiled calls
-            # (chunked cohorts); the span computes utilization on close
-            cur.attrs["flops"] = cur.attrs.get("flops", 0.0) + cost.flops
-            cur.attrs["hbm_bytes"] = (cur.attrs.get("hbm_bytes", 0.0)
-                                      + cost.hbm_bytes)
-            cur.attrs.setdefault("peak_flops", peaks["peak_flops_f32"])
-            cur.attrs.setdefault("peak_hbm_bw", peaks["hbm_bw"])
-            if cost.unknown_trip_loops:
-                cur.attrs["cost_is_lower_bound"] = True
-        return out
+        return self._jit()(*args, **kwargs)
 
 
 def profiled_jit(fn: Optional[Callable] = None, *,
                  name: Optional[str] = None,
                  static_argnames: Tuple[str, ...] = ()) -> Any:
-    """Decorator/factory: ``jax.jit`` with the sentinel + cost layer.
+    """Decorator/factory: ``jax.jit`` with the offline cost layer.
 
     Use exactly like ``functools.partial(jax.jit, static_argnames=...)``::
 

@@ -20,8 +20,9 @@ import os
 import platform
 import re
 import subprocess
-import time
 from typing import Any, Dict, List, Optional
+
+from repro.obs.timing import wall
 
 SCHEMA = "repro.obs.bench/v1"
 
@@ -94,7 +95,7 @@ def history_record(name: str, report: Dict[str, Any]) -> Dict[str, Any]:
     """One ``bench_history.jsonl`` line: schema, bench name, timestamp,
     flattened scalars, boolean claims, environment fingerprint."""
     claims = report.get("claims", {})
-    return {"schema": SCHEMA, "bench": name, "ts": time.time(),
+    return {"schema": SCHEMA, "bench": name, "ts": wall(),
             "scalars": flatten_scalars(report),
             "claims": {k: bool(v) for k, v in claims.items()},
             "fingerprint": fingerprint()}

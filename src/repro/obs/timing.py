@@ -19,6 +19,20 @@ from typing import Any, Callable, NamedTuple
 monotonic: Callable[[], float] = _time.perf_counter
 
 
+def wall() -> float:
+    """Seconds since the epoch (``time.time()``): a timestamp for records,
+    never a duration — it can step under NTP."""
+    return _time.time()
+
+
+def from_wall(t: float) -> float:
+    """A ``time.time()`` stamp (as ``jax.monitoring`` time-span events
+    carry) on the ``monotonic`` clock: the stamp's age on the wall clock,
+    taken back from ``monotonic()`` now. Meant for stamps a moment old,
+    over which the two clocks cannot drift apart."""
+    return monotonic() - (wall() - t)
+
+
 def sync(x: Any) -> Any:
     """Block until device work backing ``x`` is done (identity without
     jax, on None, or on abstract tracers during jit tracing)."""

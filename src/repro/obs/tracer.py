@@ -15,8 +15,14 @@ is returned and every hook — ``span``/``event``/``inc``/``gauge``/
 allocation, no device syncs, which is what keeps observability-off runs
 bit-identical to the seed.
 
+An enabled tracer also puts its spans on the profiler's clock: each
+``Span`` opens and closes a ``jax.profiler.TraceAnnotation`` of its name,
+so a ``jax.profiler`` trace of the run shows the span tree on its host
+plane beside the device ops. And JAX's own compile work lands in the tree
+as ``compile`` spans (``repro.obs.compiles``).
+
 Import-safe without jax (the flcheck CI job imports this transitively);
-jax is only touched lazily inside ``Span.sync``.
+jax is only touched lazily inside ``Span`` and ``Tracer``.
 """
 from __future__ import annotations
 
@@ -28,6 +34,21 @@ from repro.obs.metrics import (NULL_METRICS, MetricsRegistry)
 from repro.obs.timing import monotonic, sync as _device_sync
 
 SCHEMA = "repro.obs.trace/v1"
+COMPILE = "compile"        # the span name of JAX's compile work
+
+_ANNOTATION: Any = None
+
+
+def _annotation(name: str) -> Any:
+    """``jax.profiler.TraceAnnotation(name)``, importing jax on first use
+    (a null context without jax)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except ImportError:  # pragma: no cover - analysis-only environment
+            from contextlib import nullcontext as _ANNOTATION
+    return _ANNOTATION(name)
 
 
 class _NullSpan:
@@ -59,7 +80,7 @@ class Span:
     """One timed block in the trace tree.  Use as a context manager
     (flcheck OBS001 flags spans opened without ``with``)."""
     __slots__ = ("tracer", "span_id", "parent_id", "name", "attrs",
-                 "t0", "t1", "bytes", "frames")
+                 "t0", "t1", "bytes", "frames", "_note")
     enabled = True
 
     def __init__(self, tracer: "Tracer", span_id: int,
@@ -77,28 +98,19 @@ class Span:
 
     def __enter__(self) -> "Span":
         self.tracer._stack.append(self)
+        self._note = _annotation(self.name)
+        self._note.__enter__()
         self.t0 = monotonic()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         self.t1 = monotonic()
+        self._note.__exit__(*exc)
         top = self.tracer._stack.pop()
         if top is not self:  # pragma: no cover - programming error guard
             raise RuntimeError(
                 f"span stack corrupted: closed {self.name!r}, top was "
                 f"{top.name!r}")
-        # cost-annotated span (obs.profile attached flops + peaks): now
-        # that the duration is known, derive hardware utilization — not
-        # for "traced" spans, whose wall covers trace time, not device
-        # time, and a utilization from it would be fiction
-        a = self.attrs
-        if "flops" in a and a.get("peak_flops") and "traced" not in a:
-            dur = self.t1 - self.t0
-            if dur > 0:
-                a["utilization"] = a["flops"] / dur / a["peak_flops"]
-                if a.get("hbm_bytes") and a.get("peak_hbm_bw"):
-                    a["hbm_utilization"] = (a["hbm_bytes"] / dur
-                                            / a["peak_hbm_bw"])
         self.tracer.spans.append(self)
 
     @property
@@ -185,6 +197,8 @@ class Tracer:
     enabled = True
 
     def __init__(self, meta: Optional[Dict[str, Any]] = None) -> None:
+        from repro.obs import compiles
+        compiles.install()
         self.meta = dict(meta or {})
         self.metrics = MetricsRegistry()
         self.spans: List[Span] = []          # finished, in close order
@@ -212,6 +226,23 @@ class Tracer:
     def current(self) -> Optional[Span]:
         """The innermost open span, or None outside any span."""
         return self._stack[-1] if self._stack else None
+
+    def record_compile(self, phase: str, fn: str, t0: float, t1: float,
+                       cache_hit: bool = False) -> None:
+        """A finished ``compile`` span of JAX's compile work (``t0``/``t1``
+        on the ``monotonic`` clock) under the innermost open span, and its
+        counters: ``compile_s.<phase>`` seconds and, for the ``backend``
+        phase, ``compile.<fn>``. Fed by ``repro.obs.compiles``."""
+        attrs: Dict[str, Any] = {"phase": phase, "fn": fn}
+        if phase == "backend":
+            attrs["cache_hit"] = cache_hit
+            self.metrics.counter(f"compile.{fn}").inc()
+        self.metrics.counter(f"compile_s.{phase}").inc(t1 - t0)
+        parent = self._stack[-1].span_id if self._stack else None
+        sp = Span(self, self._next_id, parent, COMPILE, attrs)
+        self._next_id += 1
+        sp.t0, sp.t1 = t0, t1
+        self.spans.append(sp)
 
     def on_ledger(self, direction: str, category: str, nbytes: int,
                   frames: int) -> None:
@@ -309,26 +340,38 @@ def load_trace(path: str) -> Dict[str, Any]:
             "metrics": metrics}
 
 
+def span_path(by_id: Dict[int, Dict[str, Any]],
+              sp: Dict[str, Any]) -> str:
+    """``root/.../name`` of a loaded span record, ``by_id`` mapping span
+    ids to the trace's span records."""
+    parts = [sp["name"]]
+    pid = sp.get("parent")
+    guard = 0
+    while pid is not None and pid in by_id and guard < 64:
+        parts.append(by_id[pid]["name"])
+        pid = by_id[pid].get("parent")
+        guard += 1
+    return "/".join(reversed(parts))
+
+
+def is_timing_counter(name: str) -> bool:
+    """Counters of compile work (``compile.<fn>``, ``compile_s.<phase>``):
+    whether JAX compiles depends on its caches, so they are timing, not
+    structure."""
+    return name.startswith(("compile.", "compile_s."))
+
+
 def span_paths(trace: Dict[str, Any]) -> Dict[str, Dict[str, int]]:
     """Collapse a loaded trace's span tree to ``name/path`` ->
     ``{count, bytes}`` — the wall-time-free structural signature ``diff``
-    compares."""
+    compares. ``compile`` spans are left out: like wall time they depend
+    on cache state, not on what the run did."""
     by_id = {sp["id"]: sp for sp in trace["spans"]}
-
-    def path(sp: Dict[str, Any]) -> str:
-        parts = [sp["name"]]
-        pid = sp.get("parent")
-        guard = 0
-        while pid is not None and pid in by_id and guard < 64:
-            parts.append(by_id[pid]["name"])
-            pid = by_id[pid].get("parent")
-            guard += 1
-        return "/".join(reversed(parts))
-
     out: Dict[str, Dict[str, int]] = {}
     for sp in trace["spans"]:
-        p = path(sp)
-        slot = out.setdefault(p, {"count": 0, "bytes": 0})
+        if sp["name"] == COMPILE:
+            continue
+        slot = out.setdefault(span_path(by_id, sp), {"count": 0, "bytes": 0})
         slot["count"] += 1
         slot["bytes"] += sum(sp.get("bytes", {}).values())
     return out

@@ -2,8 +2,13 @@
 CLI exit codes, bit-identity of traced vs untraced zero-fault runs
 (weights AND ledger), selection-sketch regression against
 ``select_metadata``, chaos-trace fault counters vs channel totals, the
-MeteredLedger bridge, and a loose tracing-overhead smoke guard."""
+MeteredLedger bridge, a loose tracing-overhead smoke guard, and the
+compile spans and profiler annotations of a traced run."""
+import glob
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +27,7 @@ from repro.models.wrn import make_split_wrn
 from repro.obs.__main__ import main as obs_cli
 from repro.obs.timing import Timing, monotonic, timeit
 
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 NUM_CLASSES, CLUSTERS, ROUNDS = 4, 2, 2
 FL_KW = dict(num_clients=3, clients_per_round=3, local_epochs=1,
              local_batch_size=20, local_lr=0.1, pca_components=8,
@@ -96,6 +102,30 @@ class TestMetrics:
         assert tr.attributed_bytes() == {"up/metadata": 123}
         sel = [s for s in tr.spans if s.name == "select"][0]
         assert sel.bytes == {"up/metadata": 123}
+
+
+class TestStackedKnowledgeUpload:
+    def test_cohort_quantize_runs_in_an_encode_span(self):
+        from repro.fl.transport.channel import Channel
+        from repro.fl.transport.codecs import Int8Codec
+        rng = np.random.default_rng(0)
+        b, ck, d = 3, 4, 8
+        acts = jnp.asarray(rng.normal(size=(b, ck, d)), jnp.float32)
+        ys = jnp.zeros((b, ck), jnp.int32)
+        valid = jnp.ones((b, ck), bool)
+        tr = obs.Tracer()
+        with obs.use_tracer(tr):
+            with obs.span("transport", clients=b):
+                Channel(obs.MeteredLedger(tr)).upload_knowledge_batched(
+                    range(b), acts, ys, valid, Int8Codec())
+        enc = [s for s in tr.spans if s.name == "encode"]
+        cohort = [s for s in enc if "clients" in s.attrs]
+        assert len(cohort) == 1 and cohort[0].attrs == {
+            "frame": "knowledge", "clients": b}
+        # the cohort's quantize runs before the per-client framing
+        per_client = [s for s in enc if "client" in s.attrs]
+        assert len(per_client) == b
+        assert all(cohort[0].t1 <= s.t0 for s in per_client)
 
 
 class TestTracer:
@@ -208,6 +238,49 @@ class TestCLI:
             obs_cli(["no-such-command"])
         assert e.value.code == 2
 
+    def _compile_run(self, tmp_path, name, fn):
+        tr = obs.Tracer()
+        with obs.use_tracer(tr):
+            with obs.span("round", round=0):
+                fn(jnp.ones((3,), jnp.float32)).block_until_ready()
+        p = tmp_path / name
+        tr.write_jsonl(str(p))
+        return str(p), tr
+
+    def test_diff_cold_and_warm_cache_is_zero(self, tmp_path):
+        def cold_warm(x):
+            return x * 3 + 1
+
+        def compiled(tr):
+            return [s for s in tr.spans if s.name == "compile"
+                    and s.attrs["fn"] == "jit(cold_warm)"]
+
+        f = jax.jit(cold_warm)
+        a, cold = self._compile_run(tmp_path, "cold.jsonl", f)
+        b, warm = self._compile_run(tmp_path, "warm.jsonl", f)
+        assert compiled(cold) and not compiled(warm)
+        assert "compile.jit(cold_warm)" in \
+            cold.metrics.snapshot()["counters"]
+        assert "compile.jit(cold_warm)" not in \
+            warm.metrics.snapshot()["counters"]
+        assert obs_cli(["diff", a, b]) == 0
+
+    def test_summarize_shows_compile_seconds_by_parent(self, tmp_path,
+                                                       capsys):
+        def summarized(x):
+            return x - 2
+
+        p, _ = self._compile_run(tmp_path, "c.jsonl", jax.jit(summarized))
+        assert obs_cli(["summarize", p]) == 0
+        out = capsys.readouterr().out
+        line = next(l for l in out.splitlines()
+                    if l.startswith("round ") and "compile" not in l
+                    and len(l.split()) == 5)
+        n_backend, trace_s, lower_s, backend_s = map(float,
+                                                     line.split()[1:])
+        assert n_backend >= 1 and backend_s > 0 and lower_s > 0
+        assert "compile under" in out
+
     def test_export_chrome_writes_json(self, tmp_path):
         a = self._write(tmp_path, "a.jsonl")
         out = tmp_path / "chrome.json"
@@ -238,13 +311,24 @@ def runs(setting):
     """One untraced + one traced run of the same seed (untraced first, so
     it pays compile and the loose overhead guard is conservative)."""
     model, clients, test = setting
-    out = {}
+    out = {"backend_compiles": {}}
     for name, on in (("off", False), ("on", True)):
         sim = FLSimulation(model, clients, test,
                            FLConfig(**FL_KW, observability=on), seed=0)
-        t0 = monotonic()
-        res = sim.run(rounds=ROUNDS)
-        out[name] = (sim, res, monotonic() - t0)
+        seen = []
+
+        def listen(event, duration, **kwargs):
+            if event == BACKEND_COMPILE:
+                seen.append(kwargs.get("fun_name"))
+
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            t0 = monotonic()
+            res = sim.run(rounds=ROUNDS)
+            out[name] = (sim, res, monotonic() - t0)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+        out["backend_compiles"][name] = seen
     return out
 
 
@@ -303,6 +387,109 @@ class TestTracedRunFidelity:
         loaded = obs.load_trace(str(p))
         assert len(loaded["spans"]) == len(sim.tracer.spans)
         assert obs_cli(["diff", str(p), str(p)]) == 0
+
+
+def _compile_spans(tracer, phase=None):
+    return [s for s in tracer.spans if s.name == "compile"
+            and (phase is None or s.attrs["phase"] == phase)]
+
+
+class TestCompileSpans:
+    def test_every_meta_train_span_has_compile_children(self, runs):
+        sim, _, _ = runs["on"]
+        metas = [s for s in sim.tracer.spans if s.name == "meta_train"]
+        assert len(metas) == ROUNDS
+        parents = {s.parent_id for s in _compile_spans(sim.tracer)}
+        for m in metas:
+            assert m.span_id in parents
+        # the eager scan is compiled again in every round
+        fns = [s.attrs["fn"] for s in _compile_spans(sim.tracer, "backend")
+               if s.parent_id in {m.span_id for m in metas}]
+        assert fns.count("jit(scan)") >= ROUNDS
+
+    def test_compile_spans_lie_inside_their_parent(self, runs):
+        sim, _, _ = runs["on"]
+        by_id = {s.span_id: s for s in sim.tracer.spans}
+        spans = _compile_spans(sim.tracer)
+        assert spans
+        for s in spans:
+            assert s.attrs["phase"] in ("trace", "lower", "backend")
+            assert s.t0 <= s.t1
+            parent = by_id[s.parent_id]
+            assert parent.t0 <= s.t0 and s.t1 <= parent.t1, (s.attrs,
+                                                              parent.name)
+
+    def test_backend_spans_match_outside_listener(self, runs):
+        sim, _, _ = runs["on"]
+        seen = runs["backend_compiles"]["on"]
+        spans = _compile_spans(sim.tracer, "backend")
+        assert seen and len(spans) == len(seen)
+        assert sorted(s.attrs["fn"] for s in spans) == sorted(seen)
+        counters = sim.tracer.metrics.snapshot()["counters"]
+        assert sum(v for k, v in counters.items()
+                   if k.startswith("compile.")) == len(seen)
+
+    def test_observability_off_registers_and_annotates_nothing(self):
+        script = f"""
+import json
+import jax
+import jax.profiler
+from jax._src import monitoring
+opened = []
+real = jax.profiler.TraceAnnotation
+def counting(name, **kw):
+    opened.append(name)
+    return real(name, **kw)
+jax.profiler.TraceAnnotation = counting
+from repro.configs import FLConfig, get_wrn_config
+from repro.data import SyntheticImageDataset, partition_k_shards
+from repro.fl.simulation import FLSimulation
+from repro.models.wrn import make_split_wrn
+from repro.obs import compiles
+before = (len(monitoring.get_event_listeners()),
+          len(monitoring.get_event_time_span_listeners()))
+cfg = get_wrn_config().reduced()
+train = SyntheticImageDataset(80, image_size=cfg.image_size,
+                              num_classes={NUM_CLASSES}, seed=0)
+test = SyntheticImageDataset(20, image_size=cfg.image_size,
+                             num_classes={NUM_CLASSES}, seed=1)
+clients = partition_k_shards(train, 2, k_classes=2, samples_per_client=40,
+                             seed=0)
+kw = dict({FL_KW!r}, num_clients=2, clients_per_round=2)
+FLSimulation(make_split_wrn(cfg), clients, test, FLConfig(**kw),
+             seed=0).run(rounds=1)
+after = (len(monitoring.get_event_listeners()),
+         len(monitoring.get_event_time_span_listeners()))
+print(json.dumps({{"before": before, "after": after, "opened": opened,
+                  "installed": compiles._LISTENER is not None}}))
+"""
+        src = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.pathsep.join(
+                       [src, os.environ.get("PYTHONPATH", "")]))
+        p = subprocess.run([sys.executable, "-c", script], env=env,
+                           capture_output=True, text=True, timeout=600)
+        assert p.returncode == 0, p.stderr[-2000:]
+        got = json.loads(p.stdout.strip().splitlines()[-1])
+        assert got["before"] == got["after"]
+        assert got["opened"] == [] and not got["installed"]
+
+    def test_profiler_trace_holds_program_span_names(self, setting,
+                                                     tmp_path):
+        model, clients, test = setting
+        sim = FLSimulation(model, clients, test,
+                           FLConfig(**FL_KW, observability=True), seed=0)
+        with jax.profiler.trace(str(tmp_path)):
+            sim.run(rounds=1)
+        path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                                recursive=True))[-1]
+        data = jax.profiler.ProfileData.from_file(path)
+        names = {ev.name for plane in data.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert {"round", "select", "local_update", "aggregate",
+                "meta_train"} <= names
 
 
 class TestSelectionSketch:
