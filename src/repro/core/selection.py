@@ -9,7 +9,9 @@ Pipeline (per client k):
 
 Everything is pure JAX with static shapes (empty classes/clusters handled via
 masks), so it jits, vmaps over clients, and lowers inside the distributed
-train step.
+train step. One step leaves the device on a TPU: the exact PCA's top
+eigenpairs of a large matrix come from the host's LAPACK through
+``jax.pure_callback`` (``_top_eigh``).
 
 This is the system's hot path (every round, every client), so the engine is
 built around one primitive: the **fused Lloyd step** — biased distances,
@@ -37,9 +39,13 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import scipy.linalg
 
 from repro.kernels import ref as kref
 from repro.obs.profile import profiled_jit
+from repro.obs.timing import monotonic
+from repro.obs.tracer import get_tracer
 
 # the additive forbidden-column mask constant — shared with the kernel's
 # oracle (ops.py pads with it too); the f32 absorption argument in
@@ -50,26 +56,82 @@ BIG = kref.BIG
 # --------------------------------------------------------------------------
 # PCA
 # --------------------------------------------------------------------------
+# Rows from which a TPU takes the exact eigh on the host (``_top_eigh``).
+# On a v5e, for the top 200 pairs, the device Jacobi is the faster up to
+# 768 rows (34 ms against the callback's 51 ms) and the host from 1024
+# (77 ms against 280 ms).
+HOST_EIGH_MIN_N = 1024
+
+
 class PCAState(NamedTuple):
     mean: jnp.ndarray          # (D,)
     components: jnp.ndarray    # (P, D) rows = principal axes
     explained: jnp.ndarray     # (P,) eigenvalues
 
 
-def _eigh(a: jnp.ndarray):
-    """Symmetric eigendecomposition -> (ascending eigenvalues, eigenvector
-    columns), exact on every backend. On a TPU it is XLA's native Jacobi
-    ``Eigh``: JAX's default TPU lowering (QDWH spectral divide and conquer,
-    traced into the program) takes minutes to compile for the 2500 x 2500
-    Gram of one client's maps. Elsewhere it is the backend's default
-    (LAPACK on the CPU)."""
-    def jacobi(m):
-        v, w = jax.lax.linalg.eigh(
-            m, implementation=jax.lax.linalg.EighImplementation.JACOBI)
-        return w, v
+def _lapack_top_eigh(a: np.ndarray, p: int):
+    """Host side of ``_host_top_eigh``: LAPACK ``syevr`` (Householder
+    tridiagonal reduction, then MRRR for the wanted pairs only) on each
+    stacked matrix of ``a`` (..., n, n), in ``a``'s dtype. Records one
+    finished ``eigh`` span under the innermost open span of the active
+    tracer, from this call's own clock."""
+    t0 = monotonic()
+    a = np.asarray(a)
+    n = a.shape[-1]
+    flat = a.reshape(-1, n, n)
+    evals = np.empty((flat.shape[0], p), a.dtype)
+    evecs = np.empty((flat.shape[0], n, p), a.dtype)
+    for i, m in enumerate(flat):
+        # a standard problem with a subset: scipy calls ?syevr
+        w, v = scipy.linalg.eigh(m, subset_by_index=[n - p, n - 1],
+                                 check_finite=False)
+        evals[i], evecs[i] = w[::-1], v[:, ::-1]
+    tracer = get_tracer()
+    if tracer.enabled:
+        tracer.record("eigh", t0, monotonic(), n=n, p=p,
+                      dtype=str(a.dtype), clients=flat.shape[0])
+    return (evals.reshape(a.shape[:-2] + (p,)),
+            evecs.reshape(a.shape[:-1] + (p,)))
 
+
+def _host_top_eigh(a: jnp.ndarray, p: int):
+    """The top ``p`` eigenpairs of the symmetric ``a`` from the host's
+    LAPACK, through ``jax.pure_callback``. Under ``vmap`` the callback gets
+    the stacked matrices at once (one call per batch, not per matrix)."""
+    a = (a + jnp.swapaxes(a, -1, -2)) / 2           # as jnp.linalg.eigh does
+    out = (jax.ShapeDtypeStruct(a.shape[:-2] + (p,), a.dtype),
+           jax.ShapeDtypeStruct(a.shape[:-1] + (p,), a.dtype))
+    return jax.pure_callback(functools.partial(_lapack_top_eigh, p=p), out,
+                             a, vmap_method="expand_dims")
+
+
+def _top_eigh(a: jnp.ndarray, p: int):
+    """Top ``p`` eigenpairs of a symmetric matrix -> (eigenvalues in
+    descending order, their eigenvector columns), exact on every backend.
+
+    On a TPU a matrix of ``HOST_EIGH_MIN_N`` rows or more goes to the host's
+    LAPACK (``_host_top_eigh``): XLA's native ``Eigh`` is a serial Jacobi
+    loop over all n pairs (6.5 s for the 2500 x 2500 Gram of one client's
+    maps on a v5e), and JAX's default TPU eigh (QDWH, traced into the
+    program) takes minutes to compile at that size. A smaller matrix stays
+    on the chip's Jacobi ``Eigh``: there the host trip costs more than the
+    loop, a GSPMD program would gather every shard's matrices to one device
+    for the callback, and a program holding a callback is never written to
+    the persistent compile cache. Elsewhere it is the backend's default
+    (LAPACK on the CPU) and the top ``p`` kept."""
+    def full(m):
+        evals, evecs = jnp.linalg.eigh(m)           # ascending
+        return evals[::-1][:p], evecs[:, ::-1][:, :p]
+
+    def jacobi(m):
+        evecs, evals = jax.lax.linalg.eigh(
+            m, implementation=jax.lax.linalg.EighImplementation.JACOBI)
+        return evals[::-1][:p], evecs[:, ::-1][:, :p]
+
+    host = a.shape[-1] >= HOST_EIGH_MIN_N
     return jax.lax.platform_dependent(
-        a, tpu=jacobi, default=lambda m: tuple(jnp.linalg.eigh(m)))
+        a, tpu=functools.partial(_host_top_eigh, p=p) if host else jacobi,
+        default=full)
 
 
 def _pca_exact(xc: jnp.ndarray, cnt: jnp.ndarray, p: int):
@@ -77,15 +139,13 @@ def _pca_exact(xc: jnp.ndarray, cnt: jnp.ndarray, p: int):
     n, d = xc.shape
     if n <= d:
         g = (xc @ xc.T) / cnt                       # (N, N) Gram
-        evals, evecs = _eigh(g)                     # ascending
-        evals, evecs = evals[::-1][:p], evecs[:, ::-1][:, :p]
+        evals, evecs = _top_eigh(g, p)              # descending
         safe = jnp.sqrt(jnp.maximum(evals * cnt, 1e-12))
         comps = (xc.T @ evecs) / safe               # (D, P) unit-norm cols
         comps = comps.T
     else:
         cov = (xc.T @ xc) / cnt                     # (D, D)
-        evals, evecs = _eigh(cov)
-        evals, evecs = evals[::-1][:p], evecs[:, ::-1][:, :p]
+        evals, evecs = _top_eigh(cov, p)
         comps = evecs.T
     return evals, comps
 
@@ -116,8 +176,7 @@ def _pca_randomized(xc: jnp.ndarray, cnt: jnp.ndarray, p: int,
     q, _ = jnp.linalg.qr(q)                          # (D, l) orthonormal
     b = xc @ q                                       # (N, l)
     small = (b.T @ b) / cnt                          # (l, l) = Q^T C Q
-    evals, evecs = _eigh(small)
-    evals, evecs = evals[::-1][:p], evecs[:, ::-1][:, :p]
+    evals, evecs = _top_eigh(small, p)
     comps = (q @ evecs).T                            # (P, D)
     return evals, comps, b, evecs
 

@@ -26,6 +26,7 @@ jax is only touched lazily inside ``Span`` and ``Tracer``.
 """
 from __future__ import annotations
 
+import itertools
 import json
 from collections import defaultdict
 from typing import Any, Dict, Iterator, List, Optional
@@ -205,16 +206,15 @@ class Tracer:
         self.events: List[Dict[str, Any]] = []
         self.unattributed: Dict[str, int] = defaultdict(int)
         self._stack: List[Span] = []
-        self._next_id = 1
+        # next() is atomic: ``record`` may run on a host-callback thread
+        self._ids = itertools.count(1)
 
     # -- recording ---------------------------------------------------
     def span(self, name: str, **attrs: Any) -> Span:
         """New child span of the innermost open span (parent captured at
         creation); must be used as a ``with`` context manager."""
-        sid = self._next_id
-        self._next_id += 1
         parent = self._stack[-1].span_id if self._stack else None
-        return Span(self, sid, parent, name, attrs)
+        return Span(self, next(self._ids), parent, name, attrs)
 
     def event(self, name: str, **attrs: Any) -> None:
         """Record an instantaneous event under the current span."""
@@ -238,9 +238,16 @@ class Tracer:
             attrs["cache_hit"] = cache_hit
             self.metrics.counter(f"compile.{fn}").inc()
         self.metrics.counter(f"compile_s.{phase}").inc(t1 - t0)
-        parent = self._stack[-1].span_id if self._stack else None
-        sp = Span(self, self._next_id, parent, COMPILE, attrs)
-        self._next_id += 1
+        self.record(COMPILE, t0, t1, **attrs)
+
+    def record(self, name: str, t0: float, t1: float, **attrs: Any) -> None:
+        """Append a finished span (``t0``/``t1`` on the ``monotonic``
+        clock) under the innermost open span. It never touches the span
+        stack, so it is safe from a host-callback thread (the host eigh's
+        ``eigh`` spans) while the main thread holds spans open."""
+        top = self._stack[-1:]   # one read: the stack may pop meanwhile
+        parent = top[0].span_id if top else None
+        sp = Span(self, next(self._ids), parent, name, attrs)
         sp.t0, sp.t1 = t0, t1
         self.spans.append(sp)
 

@@ -180,6 +180,36 @@ class TestTracer:
         assert doc["otherData"]["schema"] == obs.SCHEMA
         assert all(e["ts"] >= 0.0 for e in doc["traceEvents"])
 
+    def _host_eigh(self):
+        from repro.core.selection import _host_top_eigh
+        rng = np.random.default_rng(0)
+        x = rng.normal(size=(3, 24, 24)).astype(np.float32)
+        a = jnp.asarray(x @ np.swapaxes(x, 1, 2))
+        return jax.jit(jax.vmap(lambda m: _host_top_eigh(m, 5)))(a)
+
+    def test_host_eigh_span_nests_under_select(self):
+        """The host eigh's callback records one finished ``eigh`` span for
+        the stacked call, under the open ``select`` span and inside it."""
+        tr = obs.Tracer()
+        with obs.use_tracer(tr):
+            with obs.span("round"):
+                with obs.span("select") as sel:
+                    jax.block_until_ready(self._host_eigh())
+        eigh = [s for s in tr.spans if s.name == "eigh"]
+        assert len(eigh) == 1
+        assert eigh[0].parent_id == sel.span_id
+        assert eigh[0].attrs == {"n": 24, "p": 5, "dtype": "float32",
+                                 "clients": 3}
+        assert sel.t0 <= eigh[0].t0 <= eigh[0].t1 <= sel.t1
+        assert len({s.span_id for s in tr.spans}) == len(tr.spans)
+
+    def test_host_eigh_records_nothing_under_null_tracer(self):
+        idle = obs.Tracer()
+        assert obs.get_tracer() is obs.NULL_TRACER
+        with obs.span("select"):
+            w, _ = jax.block_until_ready(self._host_eigh())
+        assert w.shape == (3, 5) and idle.spans == []
+
     def test_null_tracer_hooks_are_inert(self):
         # module hooks outside any use_tracer: shared singletons, no state
         sp = obs.span("anything")

@@ -414,3 +414,97 @@ class TestLloydCarriedStats:
                                       np.asarray(own))
         np.testing.assert_array_equal(np.asarray(km.cluster_sizes),
                                       np.asarray(sizes))
+
+
+def _spectrum_matrix(seed, n):
+    """A symmetric n x n matrix with eigenvalues n, n-1, ..., 1 (every gap
+    1) in a random basis, and its float64 eigenpairs in descending order."""
+    q, _ = np.linalg.qr(np.random.default_rng(seed).normal(size=(n, n)))
+    a = (q * np.arange(n, 0, -1.0)) @ q.T
+    w, v = np.linalg.eigh(a)
+    return a.astype(np.float32), w[::-1], v[:, ::-1]
+
+
+def _pca_pairs(monkeypatch, n, d):
+    """``_pca_exact`` on the host path (Gram branch when n <= d, else the
+    covariance branch) and the float64 top eigenpairs of the covariance."""
+    from repro.core import selection as S
+    monkeypatch.setattr(S, "_top_eigh", S._host_top_eigh)
+    rng = np.random.default_rng(n * d)
+    scale = np.concatenate([np.linspace(3.0, 1.5, 6),
+                            np.linspace(0.3, 0.05, 6)])
+    x = (rng.normal(size=(n, 12)) * scale) @ rng.normal(size=(12, d)) \
+        + 1e-3 * rng.normal(size=(n, d))
+    xc = x - x.mean(0)
+    w, comps = jax.jit(lambda m: S._pca_exact(m, float(n), 6))(
+        jnp.asarray(xc, jnp.float32))
+    w64, v64 = np.linalg.eigh(xc.T @ xc / n)
+    return [(w, comps.T, w64[::-1][:6], v64[:, ::-1][:, :6])]
+
+
+class TestHostTopEigh:
+    """The TPU's eigh (``_host_top_eigh``: the host's LAPACK through
+    ``jax.pure_callback``), driven on the CPU: descending eigenvalues that
+    match float64 to float32 rounding, and the same top-p subspace, under
+    every transformation the selection program applies to it."""
+
+    @pytest.mark.parametrize("case", ["jit", "vmap", "lax_map", "gram",
+                                      "cov", "p_n_minus_1"])
+    def test_matches_float64_eigh(self, case, monkeypatch):
+        from repro.core.selection import _host_top_eigh
+        n, p = 40, 7
+        if case == "p_n_minus_1":
+            p = n - 1
+        if case in ("gram", "cov"):
+            pairs = _pca_pairs(monkeypatch,
+                               *((30, 50) if case == "gram" else (60, 20)))
+        else:
+            mats = [_spectrum_matrix(s, n) for s in range(3)]
+            stack = jnp.asarray(np.stack([a for a, _, _ in mats]))
+            top = lambda m: _host_top_eigh(m, p)        # noqa: E731
+            if case == "vmap":
+                w, v = jax.jit(jax.vmap(top))(stack)
+            elif case == "lax_map":
+                w, v = jax.jit(lambda s: jax.lax.map(top, s))(stack)
+            else:
+                w, v = jax.jit(top)(stack[0])
+                w, v, mats = w[None], v[None], mats[:1]
+            pairs = [(w[i], v[i], w64[:p], v64[:, :p])
+                     for i, (_, w64, v64) in enumerate(mats)]
+        for w, v, w64, v64 in pairs:
+            w, v = np.asarray(w, np.float64), np.asarray(v, np.float64)
+            assert w.shape == w64.shape and v.shape == v64.shape
+            assert (np.diff(w) < 0).all()
+            np.testing.assert_allclose(w, w64, rtol=0,
+                                       atol=1e-5 * abs(w64[0]))
+            gap = np.linalg.norm(v @ v.T - v64 @ v64.T)
+            assert gap < 1e-4, gap
+
+    def test_host_path_picks_the_default_paths_rows(self, monkeypatch):
+        """``select_metadata`` with the host eigh picks exactly the rows of
+        the default (``jnp.linalg.eigh``) path: eigenvector signs do not
+        reach the picks, which depend only on distances in the projected
+        space. The host path's ``eigh`` span shows it ran. (Seed 1 of the
+        fixture holds a near-tie slot whose pick moves with float rounding:
+        a float64 eigh's picks differ there from the default path's too.)"""
+        from repro import obs
+        from repro.core import selection as S
+        acts, labels = structured_acts(0)
+        kw = dict(num_classes=4, clusters_per_class=5, pca_components=16,
+                  kmeans_iters=10)
+
+        def pick():   # a fresh function each call, so jit traces anew
+            return jax.jit(lambda a, l: S.select_metadata.fn(
+                a, l, KEY, **kw))(acts, labels)
+
+        want = pick()
+        monkeypatch.setattr(S, "_top_eigh", S._host_top_eigh)
+        tracer = obs.Tracer()
+        with obs.use_tracer(tracer):
+            got = pick()
+        assert [s.attrs["n"] for s in tracer.spans if s.name == "eigh"] \
+            == [min(acts.shape[0], acts[0].size)]
+        np.testing.assert_array_equal(np.asarray(got.indices),
+                                      np.asarray(want.indices))
+        np.testing.assert_array_equal(np.asarray(got.valid),
+                                      np.asarray(want.valid))

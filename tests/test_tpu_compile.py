@@ -13,8 +13,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.kernels import ops
 
@@ -24,7 +25,7 @@ COHORT = 4
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -38,9 +39,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 @pytest.fixture
@@ -81,12 +87,46 @@ def test_quantize_kernel_compiles(one_chip, mosaic, batch, feat):
     assert "tpu_custom_call" in hlo
 
 
-def test_exact_pca_compiles_to_native_eigh(one_chip):
-    """The exact PCA of one client's maps (a 2500 x 2500 Gram) must lower
-    to XLA's native Jacobi ``Eigh``: JAX's default TPU eigh (QDWH, traced
-    into the program) takes minutes to compile at this size."""
+def test_exact_pca_compiles_to_host_eigh(one_chip):
+    """The exact PCA of one client's maps (a 2500 x 2500 Gram) must take
+    its top eigenpairs from the host's native LAPACK: the program sends the
+    Gram to the host and receives the pairs back (a ``pure_callback``'s
+    host transfers), and holds neither XLA's Jacobi ``Eigh`` (a serial
+    loop, most of a round on the chip) nor JAX's default TPU eigh (QDWH,
+    which takes minutes to compile at this size). The Gram and projection
+    around it hold no loop at all."""
     from repro.core.selection import _fit_features
     lowered = jax.jit(lambda a: _fit_features(a, 200, "exact")).lower(
         jax.ShapeDtypeStruct((N, FEAT), jnp.float32, sharding=one_chip))
-    assert "custom_call @Eigh" in lowered.as_text()
-    lowered.compile()
+    text = lowered.as_text()
+    assert "stablehlo.send" in text and "stablehlo.recv" in text
+    assert "is_host_transfer = true" in text
+    assert "@Eigh" not in text and "while" not in text
+    hlo = lowered.compile().as_text()
+    assert "is_host_transfer=true" in hlo
+    for absent in ("EighTpu", "qdwh", "while"):
+        assert absent not in hlo, absent
+
+
+def test_pod_train_step_keeps_device_eigh(topology):
+    """The pod runtime's split-FL train step (``launch/steps.py``: one GSPMD
+    program over a data x model mesh) selects on each cohort's few pooled
+    rows: its exact PCA stays on the chip's Jacobi ``Eigh``. A host
+    callback there would gather every cohort's matrix to one device and
+    keep the step out of the persistent compile cache."""
+    from repro.configs import TrainConfig, get_config
+    from repro.configs.base import ShapeConfig
+    from repro.launch.dryrun import build
+    mesh = Mesh(np.array(topology.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    tcfg = TrainConfig(local_steps=1, microbatch=2, split_fl=True,
+                       meta_clusters=2, pca_components=4, remat=False,
+                       dtype="float32")
+    step, args, _, out_shardings = build(
+        get_config("llama3.2-1b").reduced(), ShapeConfig("t", 16, 4, "train"),
+        mesh, tcfg)
+    with mesh:
+        text = jax.jit(step, out_shardings=out_shardings).lower(
+            *args).as_text()
+    assert "custom_call @Eigh" in text
+    assert "is_host_transfer" not in text
