@@ -1,8 +1,10 @@
 #!/usr/bin/env python3
 """The control of the correctness check: the reference put in the
 program's place, in the nearest precision below the configuration's
-(``high``, three bfloat16 passes, for float32 at ``highest``), judged by
-the same comparison and limits as a run. It has to come out not correct.
+(for the WRN family ``high``, three bfloat16 passes, for float32 at
+``highest``), judged by the same comparison and limits as a run. The
+configuration's family (``bench/families/``) computes it. It has to come
+out not correct.
 
     python3 bench/control.py --workload <cell> --seeds 1 2 3
 
@@ -22,43 +24,34 @@ BENCH_DIR = Path(__file__).resolve().parent
 if str(BENCH_DIR) not in sys.path:
     sys.path.insert(0, str(BENCH_DIR))
 
-import build  # noqa: E402
-import reference as R  # noqa: E402
-from spec import ROOT, load_cell  # noqa: E402
-
-
-CONTROL_PRECISION = "high"
+from spec import ROOT, load_cell, load_family  # noqa: E402
 
 
 def control_numbers(cell, seed: int, rounds: int = 0, fault: str = ""):
-    """(numbers, limits) of the reference at ``high`` precision against
-    the one at the configuration's, over the cell's checked rounds, from
-    ``seed``. With ``fault`` the reference at the configuration's precision
-    with that fault planted (``reference.FAULTS``) takes the control's
-    place."""
-    config, traffic = cell.config, cell.traffic
+    """(numbers, limits) of the control against the reference, over the
+    cell's checked rounds, from ``seed``: the family's ``control``. With
+    ``fault`` the reference at the configuration's precision with that
+    fault planted (the family's ``FAULTS``) takes the control's place."""
     rounds = rounds or int(cell.workload["check_rounds"])
-    arch32 = R.arch_from(config)
-    arch16 = R.arch_from(config, CONTROL_PRECISION)
-    fl = build.fl_settings(config, traffic)
-    clients = build.make_traffic(traffic, arch32, seed)
-    w0 = R.to_host(build.make_weights(arch32, seed))
-    key = build.sim_key(seed)
-    low = R.run_rounds(arch32 if fault else arch16, fl, clients, w0, key,
-                       rounds, fault=fault)
-    ref = R.run_rounds(arch32, fl, clients, w0, key, rounds, follow=low)
-    return R.compare(low, ref, w0), cell.workload["limits"]
+    family = load_family(cell.family, ROOT)
+    return (family.control(cell.config, cell.traffic, seed, rounds,
+                           fault=fault),
+            cell.workload["limits"])
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
-    ap.add_argument("--fault", default="", choices=("",) + R.FAULTS,
-                    help="plant this fault in the reference and judge it "
-                         "in place of the control")
+    ap.add_argument("--fault", default="",
+                    help="plant this fault (one of the family's FAULTS) in "
+                         "the reference and judge it in place of the "
+                         "control")
     args = ap.parse_args(argv)
     cell = load_cell(args.workload, ROOT)
+    faults = load_family(cell.family, ROOT).FAULTS
+    if args.fault and args.fault not in faults:
+        ap.error(f"--fault must be one of {faults}")
     out = {}
     for seed in args.seeds:
         numbers, limits = control_numbers(cell, seed, fault=args.fault)

@@ -3,19 +3,21 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-A run finds a TPU or fails, turns on the persistent compile cache, builds
-the cell's traffic, weights and ``FLSimulation`` from ``--seed``, warms up
-with one round, and then drives ``FLSimulation._run_round`` (broadcast ->
-cohort -> ``FLServer.aggregate``, which yields ``W_G(t)``) round after round
-until ``--seconds`` have passed; every round started in the window counts,
-whole. Evaluation on a test set is never run. With ``--trace 0`` it reports
-the cell's end-to-end metrics; with ``--trace 1`` the program's spans are on,
-the calls into each layer carry profiler annotations, the window is traced,
-and it reports the per-layer metrics.
+A run finds a TPU or fails, turns on the persistent compile cache, has the
+configuration's model family (``bench/families/<family>.py``, see
+``bench/spec.py``) build the cell's traffic, weights and ``FLSimulation``
+from ``--seed``, warms up with one round, and then drives
+``FLSimulation._run_round`` (broadcast -> cohort -> ``FLServer.aggregate``,
+which yields ``W_G(t)``) round after round until ``--seconds`` have passed;
+every round started in the window counts, whole. Evaluation on a test set
+is never run. With ``--trace 0`` it reports the cell's end-to-end metrics;
+with ``--trace 1`` the program's spans are on, the calls into each layer
+carry profiler annotations, the window is traced, and it reports the
+per-layer metrics.
 
-After the window the program's state is freed and the plain reference
-(``bench/reference.py``) follows the first rounds from the same weights and
-keys; ``correct`` holds when every number compared is within its limit
+After the window the program's state is freed and the family's plain
+reference follows the first rounds from the same weights and keys;
+``correct`` holds when every number compared is within its limit
 (``limits`` in the cell's file). The last line of standard output is one
 JSON object; the numbers compared, each with its limit, are the last lines
 of standard error and the last key of that object.
@@ -43,7 +45,8 @@ for _p in (str(ROOT / "src"), str(BENCH_DIR)):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from spec import SpecError, load_cell, load_peaks, load_reader  # noqa: E402
+from spec import (SpecError, load_cell, load_family, load_peaks,  # noqa: E402
+                  load_reader)
 
 NEVER = 1 << 62            # an eval interval no round reaches
 WINDOW_MARK = "bench.window"
@@ -174,27 +177,28 @@ def measure(cell, seed: int, seconds: float, trace: bool,
     counter = CompileCounter()
 
     import build
-    import flops
-    import reference as R
     from repro.fl.simulation import SimulationResult
 
     config, traffic, workload = cell.config, cell.traffic, cell.workload
-    arch = R.arch_from(config)
+    family = load_family(cell.family, root)
+    fl = build.fl_settings(config, traffic)
+    arch = family.arch(config)
     t_data = clock()
-    clients = build.make_traffic(traffic, arch, seed)
+    clients = family.make_traffic(traffic, arch, seed)
     t_data = clock() - t_data
-    w0 = build.make_weights(arch, seed)
-    w0_host = R.to_host(w0)
+    w0 = family.make_weights(arch, seed)
+    w0_host = family.to_host(w0)
     check_rounds = int(workload["check_rounds"])
     capture = build.Captured(check_rounds)
-    sim, cfg = build.make_simulation(config, traffic, clients, w0, seed,
-                                     trace, capture)
+    sim, cfg = family.make_simulation(config, traffic, clients, w0, seed,
+                                      trace, capture)
     res = SimulationResult()
     t_warm, c_warm = clock(), counter.seconds
     drive_round(sim, 0, res)                      # warm-up: one round
     t_warm, c_warm = clock() - t_warm, counter.seconds - c_warm
     ledger = sim.server.ledger
-    table = json.loads((BENCH_DIR / "annotations.json").read_text())
+    table = (json.loads((BENCH_DIR / "annotations.json").read_text())
+             + list(getattr(family, "ANNOTATIONS", ())))
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else ""
 
     with ExitStack() as stack:
@@ -219,7 +223,6 @@ def measure(cell, seed: int, seconds: float, trace: bool,
         if trace:
             jax.profiler.stop_trace()
     rounds = t - 1
-    fl = build.fl_settings(config, traffic)
     updates = rounds * min(fl["clients_per_round"], fl["num_clients"])
     mem = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
               for d in jax.devices()[:cell.chips])
@@ -228,19 +231,13 @@ def measure(cell, seed: int, seconds: float, trace: bool,
         "window_s": w_end - w_start, "rounds": rounds, "updates": updates,
         "setup_s": setup_s, "uplink_bytes": up_bytes(ledger) - bytes0,
         "compiles": counter.count, "config": config, "traffic": traffic,
-        "fl": {**fl, "classes_per_client": traffic["classes_per_client"]},
+        "fl": {**fl, **family.fl_extra(traffic)},
         "peaks": load_peaks(device["kind"], root) if require_tpu else None,
         "spans": span_records(sim.tracer, w_start, w_end) if trace else [],
         "trace": None,
     }
-    n = int(traffic["samples_per_client"])
-    ctx["shapes"] = {
-        "n": n, "d": flops.split_features(config["model"]),
-        "k": config["model"]["num_classes"] * fl["clusters_per_class"],
-        "p": min(fl["pca_components"], n - 1,
-                 flops.split_features(config["model"]))}
-    ctx["flops"] = flops.round_model_flops(
-        config["model"], ctx["fl"], int(traffic["samples_per_client"]))
+    ctx["shapes"] = family.shapes(config, traffic, ctx["fl"])
+    ctx["flops"] = family.round_flops(config, traffic, ctx["fl"])
     line = {"device": dict(device, memory_peak_bytes=mem),
             "setup_parts": {"data_s": t_data, "warmup_round_s": t_warm,
                             "compile_s_in_warmup": c_warm,
@@ -265,14 +262,11 @@ def measure(cell, seed: int, seconds: float, trace: bool,
 
     dropped = int(sum(res.drops[1:]))
     # the program's state goes before the reference runs
-    prog = build.captured_rounds(capture)
+    prog = family.captured_rounds(capture)
     del sim, capture, ctx, res
     gc.collect()
     t_ref = clock()
-    ref = R.run_rounds(arch, build.fl_settings(config, traffic),
-                       clients, w0_host,
-                       build.sim_key(seed), len(prog), follow=prog)
-    numbers = R.compare(prog, ref, w0_host)
+    numbers = family.follow(arch, fl, clients, w0_host, seed, prog)
     correct, rows = judge(numbers, workload["limits"])
     if numbers.get("rounds_checked", 0) < min(check_rounds, rounds + 1):
         correct = False

@@ -3,12 +3,44 @@
 A cell is ``bench/workloads/<cell>.json``: the configuration and the traffic
 mix it runs, its chips, its ``why``, the rounds the correctness check
 follows and the limit of every number that check compares. A configuration
-is ``bench/configs/<config>.json``; a traffic mix is
+is ``bench/configs/<config>.json``; it names its model ``family``, whose
+code is ``bench/families/<family>.py``. A traffic mix is
 ``bench/traffic/<traffic>.json``; a metric is ``bench/metrics/<metric>.py``.
 ``BENCHMARK.json`` at the root says which metrics each cell reports. Every
-file is found by its name, so a new cell, configuration, mix or metric is a
-new file and an entry in ``BENCHMARK.json``, never an edit of a file that
-is there.
+file is found by its name, so a new cell, configuration, family, mix or
+metric is a new file and an entry in ``BENCHMARK.json``, never an edit of
+a file that is there.
+
+A family module gives the harness everything that depends on the model;
+``bench/run.py`` knows nothing of any model. It defines (``FAMILY_API``):
+
+- ``arch(config, precision="")``: the model's shape for the functions
+  below, at the configuration's matmul precision or at ``precision``;
+- ``make_traffic(traffic, arch, seed)``: the clients' data, from the mix
+  and the seed;
+- ``make_weights(arch, seed)``: the initial global weights, on the device;
+- ``to_host(weights)``: their host copy, which the reference starts from;
+- ``make_simulation(config, traffic, clients, weights, seed,
+  observability, capture)``: ``(sim, fl_config)``, the program's
+  ``FLSimulation`` holding those weights and recording its first rounds in
+  ``capture`` (``build.Captured``);
+- ``fl_extra(traffic)``: the keys the family adds to the readers'
+  ``ctx["fl"]``;
+- ``shapes(config, traffic, fl)``: ``n``, ``d``, ``k``, ``p`` of a client's
+  selection (rows, features, cluster slots, PCA components), for the
+  kernels' readers;
+- ``round_flops(config, traffic, fl)``: the model operations of one round,
+  by part;
+- ``captured_rounds(capture)``: the recorded rounds as host records;
+- ``follow(arch, fl, clients, weights_host, seed, rounds)``: the plain
+  reference's run of those rounds, and the numbers compared, by name, with
+  ``rounds_checked`` among them;
+- ``control(config, traffic, seed, rounds, fault="")``: those numbers for
+  the control, or for the reference with one of ``FAULTS`` planted
+  (``bench/control.py``).
+
+It may also define ``ANNOTATIONS``, entries in the form of
+``bench/annotations.json`` that the traced run adds to that table.
 """
 from __future__ import annotations
 
@@ -21,6 +53,11 @@ from typing import Any, Callable, Dict, List
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+
+
+FAMILY_API = ("arch", "make_traffic", "make_weights", "to_host",
+              "make_simulation", "fl_extra", "shapes", "round_flops",
+              "captured_rounds", "follow", "control", "FAULTS")
 
 
 class SpecError(Exception):
@@ -57,6 +94,10 @@ class Cell:
     def chips(self) -> int:
         return int(self.workload["chips"])
 
+    @property
+    def family(self) -> str:
+        return self.config["family"]
+
 
 def _metrics(bench: Dict[str, Any], kind: str) -> List[Metric]:
     out = []
@@ -77,6 +118,10 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     bdir = root / "bench"
     workload = _load_json(bdir / "workloads" / f"{name}.json")
     config = _load_json(bdir / "configs" / f"{workload['config']}.json")
+    if "family" not in config:
+        raise SpecError(f"configuration {workload['config']!r} names no "
+                        f"family")
+    _family_path(config["family"], root)
     traffic = _load_json(bdir / "traffic" / f"{workload['traffic']}.json")
     e2e = [m for m in _metrics(bench, "end_to_end")
            if not m.workloads or name in m.workloads]
@@ -85,6 +130,10 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                  if (name in m.workloads if m.workloads
                      else m.moves in reported)]
     return Cell(name, workload, config, traffic, e2e, per_layer)
+
+
+def _ident(name: str) -> str:
+    return name.replace(".", "_").replace("-", "_")
 
 
 def load_reader(metric: str, root: Path = ROOT) -> Callable[[Dict], Any]:
@@ -99,10 +148,33 @@ def load_reader(metric: str, root: Path = ROOT) -> Callable[[Dict], Any]:
     if not path.is_file():
         raise SpecError(f"no reader for metric {metric!r}: {path}")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
+        f"bench_metric_{_ident(metric)}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def _family_path(family: str, root: Path) -> Path:
+    path = root / "bench" / "families" / f"{family}.py"
+    if not path.is_file():
+        raise SpecError(f"no module for family {family!r}: {path}")
+    return path
+
+
+def load_family(family: str, root: Path = ROOT):
+    """The module ``bench/families/<family>.py``, with every name of
+    ``FAMILY_API``. It may import the benchmark's modules."""
+    path = _family_path(family, root)
+    if str(BENCH_DIR) not in sys.path:
+        sys.path.insert(0, str(BENCH_DIR))
+    spec = importlib.util.spec_from_file_location(
+        f"bench_family_{_ident(family)}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [n for n in FAMILY_API if not hasattr(mod, n)]
+    if missing:
+        raise SpecError(f"family module {path} lacks {missing}")
+    return mod
 
 
 def load_peaks(device_kind: str, root: Path = ROOT) -> Dict[str, float]:

@@ -22,6 +22,8 @@ def make_root(tmp: Path, cell: str = "wrn40-paper") -> Path:
     (root / "bench" / "workloads").mkdir()
     (root / "bench" / "traffic").mkdir()
     shutil.copytree(BENCH / "metrics", root / "bench" / "metrics")
+    shutil.copytree(BENCH / "families", root / "bench" / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(BENCH / "peaks.json", root / "bench" / "peaks.json")
     w = json.loads((BENCH / "workloads" / f"{cell}.json").read_text())
     cfg = json.loads((BENCH / "configs" / f"{w['config']}.json").read_text())
